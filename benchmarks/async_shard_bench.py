@@ -184,6 +184,7 @@ def _run(g, delta, base, mode: str, p: int, rate_per_shard=None,
 
 _DEVICE_CODE = """
 import json, time
+import jax
 import numpy as np
 from benchmarks.async_shard_bench import TOL, _workload
 from repro.streaming import DeltaGraph, update_ranks_sharded
@@ -202,6 +203,7 @@ for p in (1, 4):
                                          mode="async", transport="device")
         dt = time.perf_counter() - t0
         row = dict(mode="async", p=p, transport="device",
+                   platform=jax.devices()[0].platform,
                    s=round(dt, 3), path=stats.path,
                    supersteps=int(stats.supersteps),
                    exchanges=int(stats.exchanges),
@@ -222,6 +224,9 @@ def _device_rows(timeout: int = 1800):
     """PR 9: the device-transport throughput rows, in a forced-host-device
     subprocess (see the `device` regime note in the module docstring)."""
     env = dict(os.environ)
+    # a CPU forced-device experiment by design: the parent has touched
+    # jax, so on an accelerator host the child must never reach the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.pathsep.join([str(REPO / "src"), str(REPO)])
     out = subprocess.run([sys.executable, "-c", _DEVICE_CODE], env=env,
@@ -389,4 +394,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -63,4 +63,6 @@ def main(n=16384, nnz=131072):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -225,6 +225,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace-demo", action="store_true",
                     help="only regenerate the Perfetto kill/recovery "

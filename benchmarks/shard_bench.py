@@ -24,6 +24,7 @@ RESULTS = Path(__file__).parent / "results"
 
 _SPMD_CODE = r"""
 import json
+import jax
 import numpy as np
 from repro.graph.generate import powerlaw_webgraph
 from repro.graph.csr import TransitionT
@@ -41,6 +42,7 @@ for sched, kw in [("allgather", {}),
                      max_supersteps=4000, seed=3, **kw)
     r = solve_spmd(op, cfg)
     rows.append(dict(schedule=sched, **kw, supersteps=r.supersteps,
+                     platform=jax.devices()[0].platform,
                      err=float(np.abs(r.x - xref).max()),
                      bytes_per_step=r.comm_bytes_per_step,
                      total_comm_bytes=r.comm_bytes_total,
@@ -51,6 +53,8 @@ print(json.dumps(rows))
 
 def spmd_sparsified_bench():
     env = dict(os.environ)
+    # CPU forced-device experiment: the child must never reach a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(Path(__file__).parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", _SPMD_CODE], env=env,
@@ -118,4 +122,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

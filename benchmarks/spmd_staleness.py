@@ -13,6 +13,7 @@ RESULTS = Path(__file__).parent / "results"
 
 _CODE = r"""
 import json
+import jax
 import numpy as np
 from repro.graph.generate import powerlaw_webgraph
 from repro.graph.csr import TransitionT
@@ -35,7 +36,7 @@ for sched, kw in [("allgather", {}),
     err = float(np.abs(r.x - xref).max())
     total = r.comm_bytes_per_step * r.supersteps
     rows.append(dict(schedule=sched, **kw, supersteps=r.supersteps,
-                     err=err, bytes_per_step=r.comm_bytes_per_step,
+                     platform=jax.devices()[0].platform, err=err, bytes_per_step=r.comm_bytes_per_step,
                      total_comm_bytes=total))
 print(json.dumps(rows))
 """
@@ -43,6 +44,8 @@ print(json.dumps(rows))
 
 def main():
     env = dict(os.environ)
+    # CPU forced-device experiment: the child must never reach a chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(Path(__file__).parents[1] / "src")
     out = subprocess.run([sys.executable, "-c", _CODE], env=env,

@@ -61,15 +61,15 @@ class BackendSpec:
             raise ValueError(f"unknown backend {name!r}; expected one of "
                              f"{BACKENDS}")
         impl, bm = self.impl, self.bm
-        on_accel = jax.default_backend() in ("tpu", "gpu")
         on_tpu = jax.default_backend() == "tpu"
         if impl == "auto":
-            # the *solver* auto policy: compiled Pallas on a real
-            # accelerator, the fast blocked-einsum oracle on CPU (same
+            # the *solver* auto policy: compiled Pallas on TPU (the only
+            # backend the Mosaic kernel compiles for), the fast
+            # blocked-einsum oracle elsewhere (same
             # math; interpret mode is the kernel-faithful-but-slow lane
             # the kernel-level dispatch prefers — see
             # kernels.bsr_spmv.resolve_impl)
-            impl = "pallas" if on_accel else "ref"
+            impl = "pallas" if on_tpu else "ref"
         if bm == 0:
             # the MXU wants 128x128 tiles; the XLA einsum path wants the
             # highest fill (fewest padded flops/pages), which small blocks

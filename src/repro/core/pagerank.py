@@ -259,7 +259,7 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
     # bf16/f32 model code in the same process. The bsr path is float32
     # end to end, so it never needs the x64 scope.
     use_x64 = dtype == jnp.float64 and spec.name == "segment_sum"
-    ctx = jax.experimental.enable_x64() if use_x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if use_x64 else contextlib.nullcontext()
     with ctx:
         dev, meta, x0_dev = prepare(op, spec, dtype=dtype, v=v, x0=x0)
         tol_vec = as_lane_tol(tol, meta.nv)

@@ -61,7 +61,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .partition import Partition, block_rows
 # submodule reference (see des.py): runtime.driver imports core.termination,
@@ -137,6 +136,7 @@ class SPMDResult:
     # comm_bytes_total == sum(c["bytes"]) and rows_sent == sum(c["rows"])
     # across the log (pinned by tests/test_observe.py)
     chunk_log: Optional[List[dict]] = None
+    devices: int = 0             # devices the sharded operands span
 
 
 # the accept-draw hash moved to the shared step module; kept under the
@@ -299,7 +299,10 @@ def solve_spmd(op: GoogleOperator, cfg: SPMDConfig,
     if mesh is None:
         devs = jax.devices()
         assert len(devs) >= p, f"need {p} devices, have {len(devs)}"
-        mesh = jax.make_mesh((p,), ("ue",), devices=devs[:p])
+        # Auto pinned for the reason given in runtime/device.py::_mesh
+        mesh = jax.make_mesh((p,), ("ue",),
+                             axis_types=(jax.sharding.AxisType.Auto,),
+                             devices=devs[:p])
 
     v_stack = np.asarray(op.teleport() if v is None else v,
                          dtype=np.float64)
@@ -407,14 +410,14 @@ def solve_spmd(op: GoogleOperator, cfg: SPMDConfig,
             return (frag[None], step[None], resid[None], lane_step[None],
                     rows_sent[None], fulls[None])
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body_fn, mesh=mesh,
             in_specs=(P("ue", None, None), P("ue", None), P("ue", None),
                       P("ue", None, None))
             + tuple(P("ue", *([None] * (a.ndim - 1))) for a in op_args),
             out_specs=(P("ue", None, None), P("ue"), P("ue", None),
                        P("ue", None), P("ue"), P("ue")),
-            check_rep=False,
+            check_vma=False,
         )
         frags, steps, resids, lane_steps, rows_sent, fulls = \
             jax.jit(mapped)(vblk, valid, dang, x0, *op_args)
@@ -553,4 +556,5 @@ def solve_spmd(op: GoogleOperator, cfg: SPMDConfig,
                       comm_bytes_total=int(comm_total),
                       rows_sent=int(rows_total),
                       lane_supersteps=lane_out if nv > 1 else None,
-                      lane_chunks=chunks, chunk_log=chunk_log)
+                      lane_chunks=chunks, chunk_log=chunk_log,
+                      devices=len(valid.sharding.device_set))

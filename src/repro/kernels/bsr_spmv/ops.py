@@ -26,6 +26,10 @@ from .bsr_spmv import bsr_spmv, DEFAULT_BM, DEFAULT_BN
 from .ref import bsr_spmv_ref
 from ...graph.csr import TransitionT
 
+# the dense-block array is uploaded whole to one device: past one v5e
+# chip's 16 GB of HBM it cannot be placed at all, so refuse to pack it
+MAX_BLOCK_BYTES = 16 * 10**9
+
 
 @dataclasses.dataclass(frozen=True)
 class BSRMatrix:
@@ -132,7 +136,7 @@ def build_bsr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     slot[order] = slot_sorted
 
     est = nbr * K * bm * bn * 4
-    if est > 8 << 30:
+    if est > MAX_BLOCK_BYTES:
         raise MemoryError(
             f"BSR dense-block array would be {est/1e9:.1f} GB "
             f"(K={K}); use build_hybrid_bsr (hub split), reordering, or "
@@ -259,8 +263,9 @@ IMPLS = ("auto", "pallas", "interpret", "ref")
 
 def resolve_impl(impl: str = "auto") -> str:
     """Kernel-dispatch policy: "auto" picks the compiled Pallas kernel on a
-    real TPU/GPU and interpret mode elsewhere (the faithful kernel
-    semantics on hosts with no Mosaic backend); an explicit impl is passed
+    TPU (the kernel is Mosaic-only) and interpret mode elsewhere (the
+    faithful kernel semantics on hosts with no Mosaic backend); an
+    explicit impl is passed
     through untouched — the kernel tests' override.  (The *solver* policy,
     which prefers the fast blocked-einsum oracle on CPU, lives in
     core.backend.BackendSpec.resolved() — same math, different speed
@@ -269,16 +274,15 @@ def resolve_impl(impl: str = "auto") -> str:
         raise ValueError(f"unknown impl {impl!r}; expected one of {IMPLS}")
     if impl != "auto":
         return impl
-    return "pallas" if jax.default_backend() in ("tpu", "gpu") \
-        else "interpret"
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
 
 
 def bsr_matvec(blocks: jax.Array, blk_cols: jax.Array, x: jax.Array,
                impl: str = "auto", accum: str = "f32") -> jax.Array:
     """Dispatch the block multiply: Pallas kernel, interpret mode, or the
     jnp blocked-einsum oracle (same math, XLA-compiled — the CPU path).
-    impl="auto" resolves via `resolve_impl` (pallas on real TPU/GPU,
-    interpret elsewhere).  `accum` selects the accumulation lane: "f32"
+    impl="auto" resolves via `resolve_impl` (pallas on TPU, interpret
+    elsewhere).  `accum` selects the accumulation lane: "f32"
     (default), "kahan" (compensated summation — on the kernel paths a
     scratch-carried Kahan sum, on the ref path the f64-accumulate limit
     cast back to f32), or "f64" (ref path only: full f64 accumulate,

@@ -20,13 +20,13 @@ traced ShardStep the SPMD solver runs (runtime/step.py):
 
 On CPU, p shard programs are exercised with
 `XLA_FLAGS=--xla_force_host_platform_device_count=p` (the forced-host-
-device idiom the multidevice tests use); on TPU/GPU the mesh maps onto
+device idiom the multidevice tests use); on TPU the mesh maps onto
 real devices.
 
 Numerics contract: the streaming updater certifies ||x - x*||_1 <= tol at
 tol = 1e-8 scales, below the float32 representation floor (~n * eps32) —
-so the transport runs the whole program under `jax.experimental.
-enable_x64` when `dtype="float64"` (the default), with the segment-sum
+so the transport runs the whole program under `jax.enable_x64(True)`
+when `dtype="float64"` (the default), with the segment-sum
 backend whose operator slices are packed in the run dtype.  The BSR
 backend keeps its blocks in float32 (the MXU layout); it is the TPU
 rendering for looser tolerances and carries the compensated-summation
@@ -56,6 +56,7 @@ class DeviceRunResult:
     converged: bool              # in-loop Fig. 1 fired before the step cap
     p: int = 0
     schedule: str = ""
+    devices: int = 0             # devices the shard program's output spans
 
 
 class DeviceShardTransport:
@@ -117,7 +118,13 @@ class DeviceShardTransport:
                 f"device transport needs {self.p} devices, have "
                 f"{len(devs)}; on CPU launch with XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={self.p}")
-        return jax.make_mesh((self.p,), ("ue",), devices=devs[: self.p])
+        # Auto: the shard_map body is fully manual and nothing outside it
+        # reads sharding-in-types, so Auto and Explicit compile to the same
+        # program (bitwise-equal results on 4 host devices); Auto is pinned
+        # because JAX 0.9 changed make_mesh's default to Explicit
+        return jax.make_mesh((self.p,), ("ue",),
+                             axis_types=(jax.sharding.AxisType.Auto,),
+                             devices=devs[: self.p])
 
     # -- the drain -------------------------------------------------------
     def run(self, op, x0: np.ndarray, *, target: float,
@@ -134,8 +141,8 @@ class DeviceShardTransport:
         criterion.
         """
         if self.dtype == "float64":
-            from jax.experimental import enable_x64
-            with enable_x64():
+            import jax
+            with jax.enable_x64(True):
                 return self._run(op, x0, target=target,
                                  max_supersteps=max_supersteps, v=v)
         return self._run(op, x0, target=target,
@@ -147,7 +154,6 @@ class DeviceShardTransport:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         from ..core.partition import block_rows
         from ..core.spmd import SPMDConfig, _pack_blocks, _resolve_bsr
@@ -252,14 +258,14 @@ class DeviceShardTransport:
             return (frag[None], step[None], dl1[None],
                     lane_done[None], rows_sent[None], fulls[None])
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body_fn, mesh=mesh,
             in_specs=(P("ue", None, None), P("ue", None), P("ue", None),
                       P("ue", None, None))
             + tuple(P("ue", *([None] * (a.ndim - 1))) for a in op_args),
             out_specs=(P("ue", None, None), P("ue"), P("ue"),
                        P("ue", None), P("ue"), P("ue")),
-            check_rep=False,
+            check_vma=False,
         )
         frags, steps, dl1, lane_done, rows_sent, fulls = \
             jax.jit(mapped)(vblk, valid, dang, x0_dev, *op_args)
@@ -281,4 +287,5 @@ class DeviceShardTransport:
             fulls=fulls_total, comm_bytes_total=comm_total,
             device_resid=float(np.asarray(dl1)[0]),
             converged=bool(np.asarray(lane_done).all()),
-            p=p, schedule=self.exchange)
+            p=p, schedule=self.exchange,
+            devices=len(frags.sharding.device_set))

@@ -103,6 +103,7 @@ class ShardedUpdateStats:
     fulls: int = 0             # forced full refreshes (bounded-delay)
     device_resid: float = 0.0  # final device-visible delta L1 (telemetry;
     #                          # the published cert is the exact recompute)
+    devices: int = 0           # devices the shard program's output spans
 
 
 def _scatter_add(out: np.ndarray, idx: np.ndarray,
@@ -351,6 +352,7 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
     bytes_total = 0
     attempts = 0
     device_resid = 0.0
+    devices = 0
     resid = float(np.abs(r).sum())
     while (attempts == 0 or resid > l1_target) and attempts < 4:
         attempts += 1
@@ -361,6 +363,7 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
         fulls += res.fulls
         bytes_total += res.comm_bytes_total
         device_resid = res.device_resid
+        devices = res.devices
         # re-derive the maintained residual exactly from the new iterate
         # (one O(nnz) host apply) — both the re-entry decision and the
         # published certificate stand on it
@@ -376,7 +379,7 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
             cert=resid / (1.0 - alpha), stop_superstep=supersteps,
             mode="async", attempts=attempts, transport="device",
             rows_sent=rows, fulls=fulls, device_resid=device_resid,
-            schedule=schedule_name)
+            schedule=schedule_name, devices=devices)
     return _solver_fallback(
         dg, state, alpha=alpha, tol=tol, method=method, backend=backend,
         solver_max_iters=solver_max_iters,
@@ -385,7 +388,8 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
                       bytes_moved=bytes_total, seed_l1=seed_l1,
                       mode="async", attempts=max(attempts, 1),
                       transport="device", rows_sent=rows, fulls=fulls,
-                      device_resid=device_resid, schedule=schedule_name))
+                      device_resid=device_resid, schedule=schedule_name,
+                      devices=devices))
 
 
 def update_ranks_sharded(

@@ -26,7 +26,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core.des import AsyncDES, DESConfig
 from ..core.partition import Partition, block_rows
@@ -174,11 +173,11 @@ def make_local_sgd_step(loss_fn: Callable, lr: float, sync_every: int,
         return jax.tree_util.tree_map(lambda x: x[None], params)
 
     n = mesh.shape[axis]
-    mapped = shard_map(
+    mapped = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(axis),
-        check_rep=False)
+        check_vma=False)
 
     def step(params, batches):
         # params enter replicated: tile across the axis for shard_map

@@ -8,6 +8,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
     env = dict(os.environ)
+    # a CPU experiment by design: a child of a process that has touched
+    # jax must never reach an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", code], env=env,

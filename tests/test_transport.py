@@ -297,6 +297,7 @@ print("LEFTOVERS:", left)
 def test_procpool_killed_worker_recovers_no_shm_leak():
     before = set(_shm_leftovers())
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # host-only child of a jax parent
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     out = subprocess.run([sys.executable, "-c", _REAPER_SCRIPT], env=env,
