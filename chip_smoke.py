@@ -380,7 +380,7 @@ def phase_sharded(g, clock, rng) -> None:
         x_ref, ref_cert = reference(op)
         out = {}
         for p in (1, 4):
-            cfg = SPMDConfig(p=p, kind="linear", tol=1e-10,
+            cfg = SPMDConfig(p=p, kind="linear", tol=1e-8,
                              max_supersteps=2000)
             c0 = clock.secs
             res, wall = timed(solve_spmd, op, cfg)
