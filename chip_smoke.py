@@ -53,6 +53,11 @@ KNOWN_KINDS = ("TPU v5 lite",)
 # the power method on this graph (see PERF.md, "Findings").
 BSR_TOL = 1e-5
 SERVE_TOL = 1e-8        # certified L1 error of every served batch
+# solve_spmd (--chips 4) runs in f32 and stops on a per-shard inf-norm
+# change of SPMD_LOCAL_TOL; its answer is held to the f32 solve's exact
+# residual threshold, and to unit mass, before anything is normalized
+SPMD_LOCAL_TOL = 1e-8
+SPMD_TOL = BSR_TOL
 PPR_TOL = 1e-4          # certified L1 error of every personalized lane
 N_QUERIES = 16
 TOP = 100
@@ -93,7 +98,8 @@ class CompileClock:
 
 
 class Phase:
-    """Times one phase and prints its JSON line on success."""
+    """Times one phase and prints its JSON line, with "failed" set when
+    one of its checks failed."""
 
     def __init__(self, name: str, clock: CompileClock):
         self.clock = clock
@@ -105,7 +111,7 @@ class Phase:
         return self.rec
 
     def __exit__(self, exc_type, exc, tb):
-        if exc_type is not None:
+        if exc_type is not None and exc_type is not SmokeFailure:
             return False
         import jax
         self.rec.update(
@@ -114,6 +120,9 @@ class Phase:
             cache_hits=self.clock.hits - self.h0,
             peak_bytes_in_use=[(d.memory_stats() or {}).get(
                 "peak_bytes_in_use") for d in jax.local_devices()])
+        if exc is not None:
+            # a failed check still shows what the phase measured
+            self.rec["failed"] = str(exc)
         print(json.dumps(self.rec), flush=True)
         return False
 
@@ -357,7 +366,7 @@ def phase_sharded(g, clock, rng) -> None:
                   f"{stats.devices} devices")
             runs[(p, exchange)] = (st.x, stats, wall, clock.secs - c0)
         x_ref, ref_cert = reference(dg.operator(ALPHA))
-        x1, s1 = runs[(1, "allgather")][:2]
+        x1 = runs[(1, "allgather")][0]
         rows = []
         for (p, exchange), (x, stats, wall, comp) in runs.items():
             err = float(np.abs(x - x_ref).sum())
@@ -369,37 +378,51 @@ def phase_sharded(g, clock, rng) -> None:
                              rows_sent=stats.rows_sent, fulls=stats.fulls,
                              bytes_moved=stats.bytes_moved, cert=stats.cert,
                              l1_err=err, l1_vs_one_chip=gap))
-            check(err <= stats.cert + ref_cert, f"p={p} {exchange} L1 error {err:.3e}"
-                  f" > cert {stats.cert:.3e}")
-            check(gap <= stats.cert + s1.cert,
+            # every cert is the host's exact recompute and already
+            # checked against SERVE_TOL; the distances are held to that
+            # fixed bound, not to the computed certificates
+            check(err <= SERVE_TOL + ref_cert,
+                  f"p={p} {exchange} L1 error {err:.3e} > {SERVE_TOL}")
+            check(gap <= 2 * SERVE_TOL,
                   f"p={p} {exchange} differs from one chip by {gap:.3e}")
         rec.update(tol=SERVE_TOL, runs=rows)
 
     with Phase("sharded_spmd", clock) as rec:
         op = GoogleOperator(pt=TransitionT.from_graph(g), alpha=ALPHA)
         x_ref, ref_cert = reference(op)
+        bound = SPMD_TOL / (1.0 - ALPHA)
         out = {}
         for p in (1, 4):
-            cfg = SPMDConfig(p=p, kind="linear", tol=1e-8,
+            cfg = SPMDConfig(p=p, kind="linear", tol=SPMD_LOCAL_TOL,
                              max_supersteps=2000)
             c0 = clock.secs
             res, wall = timed(solve_spmd, op, cfg)
-            check(res.devices == p, f"solve_spmd p={p} spans "
-                  f"{res.devices} devices")
-            x = res.x / res.x.sum()
-            resid, cert = certify(op, x)
-            out[p] = (x, cert)
+            # the linear form keeps unit mass; a shard's lost or doubled
+            # fragment shows here and in the exact residual
+            mass = float(res.x.sum())
+            resid, cert = certify(op, res.x)
+            out[p] = res.x
             rec[f"p{p}"] = dict(devices=res.devices, wall_s=wall,
                                 compile_s=clock.secs - c0,
-                                supersteps=res.supersteps, cert=cert,
-                                l1_err=float(np.abs(x - x_ref).sum()))
-            check(rec[f"p{p}"]["l1_err"] <= cert + ref_cert,
-                  f"solve_spmd p={p} L1 error above its certificate")
+                                supersteps=res.supersteps,
+                                mass_err=abs(mass - 1.0), exact_resid=resid,
+                                cert=cert,
+                                l1_err=float(np.abs(res.x - x_ref).sum()))
+            check(res.devices == p, f"solve_spmd p={p} spans "
+                  f"{res.devices} devices")
             check(res.supersteps < cfg.max_supersteps,
                   f"solve_spmd p={p} hit max_supersteps")
-        gap = float(np.abs(out[4][0] - out[1][0]).sum())
-        rec.update(kind="linear", dtype="float32", l1_p4_vs_p1=gap)
-        check(gap <= out[4][1] + out[1][1],
+            check(abs(mass - 1.0) <= SPMD_TOL,
+                  f"solve_spmd p={p} mass {mass!r} is not 1")
+            check(resid <= SPMD_TOL, f"solve_spmd p={p} residual "
+                  f"{resid:.3e} above tol {SPMD_TOL}")
+            check(rec[f"p{p}"]["l1_err"] <= bound + ref_cert,
+                  f"solve_spmd p={p} L1 error "
+                  f"{rec[f'p{p}']['l1_err']:.3e} > {bound:.3e}")
+        gap = float(np.abs(out[4] - out[1]).sum())
+        rec.update(kind="linear", dtype="float32", local_tol=SPMD_LOCAL_TOL,
+                   tol=SPMD_TOL, l1_p4_vs_p1=gap)
+        check(gap <= 2 * bound,
               f"solve_spmd p=4 differs from p=1 by {gap:.3e}")
 
 

@@ -42,8 +42,9 @@ DEFAULT_BN = 128
 
 # the MXU's default f32 matmul is one bf16 pass (~3 significant digits):
 # on v5e it left the power method's L1 residual stuck near 5e-4 on the
-# Stanford-Web replica. HIGHEST is full f32; the kernel is HBM-bound, so
-# the extra MXU passes are hidden behind the block loads
+# Stanford-Web replica. HIGHEST is full f32; there it cost about 13% per
+# power iteration on v5e (~66 ms against ~58 ms). The 3-pass HIGH was not
+# tried
 _PRECISION = jax.lax.Precision.HIGHEST
 
 # flat column-table bytes per pallas_call: a quarter of v5e's 1 MiB SMEM,
