@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.google import GoogleOperator
+from ..runtime.observe import span
 from .backend import (BackendSpec, BackendMeta, as_lane_tol, as_spec,
                       prepare, from_layout, google_apply, l1_residual,
                       take_lanes)
@@ -261,26 +262,30 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
     use_x64 = dtype == jnp.float64 and spec.name == "segment_sum"
     ctx = jax.enable_x64(True) if use_x64 else contextlib.nullcontext()
     with ctx:
-        dev, meta, x0_dev = prepare(op, spec, dtype=dtype, v=v, x0=x0)
+        with span("solver.prepare"):
+            dev, meta, x0_dev = prepare(op, spec, dtype=dtype, v=v, x0=x0)
         tol_vec = as_lane_tol(tol, meta.nv)
         freeze = (meta.nv >= 8 if freeze_lanes == "auto"
                   else bool(freeze_lanes)) and meta.nv > 1
-        if freeze:
-            x, resid, iters, lane_iters = _solve_frozen(
-                dev, x0_dev, meta, linear, tol_vec, max_iters, freeze_chunk)
-        else:
-            x_dev, resid, iters = _solve_jit(
-                dev, x0_dev, jnp.asarray(tol_vec, x0_dev.dtype), meta=meta,
-                linear=linear, max_iters=max_iters)
-            x = from_layout(meta, x_dev)
-            resid = np.asarray(resid, dtype=np.float64)
-            iters = int(iters)
-            lane_iters = np.full(meta.nv, iters, dtype=np.int64)
+        with span("solver.loop"):
+            if freeze:
+                x, resid, iters, lane_iters = _solve_frozen(
+                    dev, x0_dev, meta, linear, tol_vec, max_iters,
+                    freeze_chunk)
+            else:
+                x_dev, resid, iters = _solve_jit(
+                    dev, x0_dev, jnp.asarray(tol_vec, x0_dev.dtype),
+                    meta=meta, linear=linear, max_iters=max_iters)
+                x = from_layout(meta, x_dev)
+                resid = np.asarray(resid, dtype=np.float64)
+                iters = int(iters)
+                lane_iters = np.full(meta.nv, iters, dtype=np.int64)
 
-    if perm is not None:
-        x = x[perm]
-    s = x.sum(axis=0)
-    x = np.where(s > 0, x / np.where(s > 0, s, 1.0), x)
+    with span("solver.finish"):
+        if perm is not None:
+            x = x[perm]
+        s = x.sum(axis=0)
+        x = np.where(s > 0, x / np.where(s > 0, s, 1.0), x)
     nv = x.shape[1]
     if squeeze and nv == 1:
         x = x[:, 0]

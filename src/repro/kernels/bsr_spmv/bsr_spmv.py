@@ -143,6 +143,9 @@ def bsr_spmv(blocks: jax.Array, blk_cols: jax.Array, x: jax.Array,
             ),
             out_shape=jax.ShapeDtypeStruct((rows, bm, nv), jnp.float32),
             interpret=interpret,
+            # one name for every chunk and both lanes: the profile shows
+            # the kernel as `bsr_spmv` or `bsr_spmv.<n>`
+            name="bsr_spmv",
         )(flat_cols[row0 * K:(row0 + rows) * K], blocks, x)
 
     parts = [call(row0, rows) for row0, rows in row_chunks(nbr, K)]

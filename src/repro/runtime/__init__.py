@@ -69,7 +69,7 @@ from .faults import (FaultPlan, FaultState, FaultyContext,
                      InjectedWorkerKill)
 from .observe import (EV_NAMES, OBS_COUNTERS, ShardObserver,
                       attribute_frontier, chrome_trace, render_prometheus,
-                      write_chrome_trace)
+                      span, write_chrome_trace)
 from .schedule import (DEFAULT_SCHEDULE, SCHEDULES, DrainOrder,
                        ExchangeGate, PriorityOrder, RandomizedOrder,
                        ScheduleSpec, make_schedule)
@@ -93,7 +93,7 @@ __all__ = [
     "FaultPlan", "FaultState", "FaultyContext", "InjectedWorkerKill",
     "BackoffPolicy", "RestartEvent", "ShardSupervisor",
     "ShardObserver", "EV_NAMES", "OBS_COUNTERS", "attribute_frontier",
-    "chrome_trace", "write_chrome_trace", "render_prometheus",
+    "chrome_trace", "write_chrome_trace", "render_prometheus", "span",
     "ScheduleSpec", "SCHEDULES", "DEFAULT_SCHEDULE", "make_schedule",
     "DrainOrder", "PriorityOrder", "RandomizedOrder", "ExchangeGate",
     "Channel", "TransportContext", "WorkerConfig", "shard_worker_loop",

@@ -39,9 +39,11 @@ cross-checked by benchmarks/check_device_transport.py.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Dict, Optional
 
 import numpy as np
+
+from .observe import span
 
 
 @dataclasses.dataclass
@@ -129,7 +131,8 @@ class DeviceShardTransport:
     # -- the drain -------------------------------------------------------
     def run(self, op, x0: np.ndarray, *, target: float,
             max_supersteps: int = 2000,
-            v: Optional[np.ndarray] = None) -> DeviceRunResult:
+            v: Optional[np.ndarray] = None,
+            phase_s: Optional[Dict[str, float]] = None) -> DeviceRunResult:
         """Drain `op`'s linear form (eq. 7) from warm start `x0` until the
         all-reduced fragment-delta L1 holds <= `target` for the Fig. 1
         persistence window, or `max_supersteps` elapse.
@@ -139,18 +142,27 @@ class DeviceShardTransport:
         margin and publishes only the host-side exact-residual
         certificate (incremental._exact_residual), never this loop's own
         criterion.
+
+        The run is three spans (`runtime.observe.span`), whose wall
+        seconds are added to `phase_s` when it is given:
+        `transport.pack` (host layout, exchange plan, uploads),
+        `transport.dispatch` (trace, lower, compile or cache read,
+        enqueue) and `transport.fetch` (reading the outputs back, which
+        waits for the device program to finish).
         """
         if self.dtype == "float64":
             import jax
             with jax.enable_x64(True):
                 return self._run(op, x0, target=target,
-                                 max_supersteps=max_supersteps, v=v)
+                                 max_supersteps=max_supersteps, v=v,
+                                 phase_s=phase_s)
         return self._run(op, x0, target=target,
-                         max_supersteps=max_supersteps, v=v)
+                         max_supersteps=max_supersteps, v=v,
+                         phase_s=phase_s)
 
     def _run(self, op, x0: np.ndarray, *, target: float,
-             max_supersteps: int, v: Optional[np.ndarray]
-             ) -> DeviceRunResult:
+             max_supersteps: int, v: Optional[np.ndarray],
+             phase_s: Optional[Dict[str, float]]) -> DeviceRunResult:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
@@ -174,57 +186,60 @@ class DeviceShardTransport:
             raise ValueError(f"device transport is single-lane; teleport "
                              f"has shape {v_stack.shape}")
 
-        # reuse the SPMD packer verbatim (one packing layout to maintain);
-        # only the schedule/backend fields are consulted by _pack_blocks
-        cfg = SPMDConfig(p=p, schedule=self.exchange, dtype=self.dtype,
-                         backend=self.backend, bsr_bm=self.bsr_bm,
-                         bsr_impl=self.bsr_impl)
-        part = block_rows(n, p)
-        packed = _pack_blocks(op, part, np_dtype, cfg, v_stack)
-        bsize, n_pad = packed["bsize"], packed["n_pad"]
-        use_bsr = self.backend == "bsr_pallas"
-        if use_bsr:
-            bm, bsr_impl = _resolve_bsr(cfg)
-
         x0 = np.asarray(x0, dtype=np.float64)
         if x0.shape != (n,):
             raise ValueError(f"x0 has shape {x0.shape}, expected ({n},)")
-        x0_blocks = np.zeros((p, bsize, 1), dtype=np_dtype)
-        for i in range(p):
-            s, t = part.block(i)
-            x0_blocks[i, : t - s, 0] = x0[s:t]
+        with span("transport.pack", into=phase_s):
+            # reuse the SPMD packer verbatim (one packing layout to
+            # maintain); only the schedule/backend fields are consulted
+            cfg = SPMDConfig(p=p, schedule=self.exchange, dtype=self.dtype,
+                             backend=self.backend, bsr_bm=self.bsr_bm,
+                             bsr_impl=self.bsr_impl)
+            part = block_rows(n, p)
+            packed = _pack_blocks(op, part, np_dtype, cfg, v_stack)
+            bsize, n_pad = packed["bsize"], packed["n_pad"]
+            use_bsr = self.backend == "bsr_pallas"
+            if use_bsr:
+                bm, bsr_impl = _resolve_bsr(cfg)
 
-        init_comm, comm = spmd_exchange(
-            self.exchange, p=p, bsize=bsize, n_pad=n_pad,
-            sync_every=self.sync_every, sparsify_k=self.sparsify_k,
-            sparsify_row_thresh=self.sparsify_thresh,
-            sparsify_refresh_every=self.sparsify_refresh_every,
-            sparsify_adaptive=self.sparsify_adaptive,
-            # endgame guard at the drain target's scale: near-converged
-            # delta mass ships full payloads so the persistence window
-            # can settle
-            sparsify_endgame_mass=target)
+            x0_blocks = np.zeros((p, bsize, 1), dtype=np_dtype)
+            for i in range(p):
+                s, t = part.block(i)
+                x0_blocks[i, : t - s, 0] = x0[s:t]
 
-        sh = lambda *spec: jax.NamedSharding(mesh, P(*spec))
-        valid = jax.device_put(packed["valid"], sh("ue", None))
-        dang = jax.device_put(
-            np.broadcast_to(packed["dang"], (p, n_pad)).copy(),
-            sh("ue", None))
-        vblk = jax.device_put(packed["vblk"].astype(np_dtype),
-                              sh("ue", None, None))
-        x0_dev = jax.device_put(x0_blocks, sh("ue", None, None))
-        if use_bsr:
-            op_args = tuple(
-                jax.device_put(packed[k], sh("ue", *([None] * nd)))
-                for k, nd in (("blk", 4), ("bcols", 2), ("hrow", 1),
-                              ("hcol", 1), ("hval", 1)))
-        else:
-            op_args = tuple(jax.device_put(packed[k], sh("ue", None))
-                            for k in ("src", "wgt", "rid"))
+            init_comm, comm = spmd_exchange(
+                self.exchange, p=p, bsize=bsize, n_pad=n_pad,
+                sync_every=self.sync_every, sparsify_k=self.sparsify_k,
+                sparsify_row_thresh=self.sparsify_thresh,
+                sparsify_refresh_every=self.sparsify_refresh_every,
+                sparsify_adaptive=self.sparsify_adaptive,
+                # endgame guard at the drain target's scale:
+                # near-converged delta mass ships full payloads so the
+                # persistence window can settle
+                sparsify_endgame_mass=target)
+
+            sh = lambda *spec: jax.NamedSharding(mesh, P(*spec))
+            valid = jax.device_put(packed["valid"], sh("ue", None))
+            dang = jax.device_put(
+                np.broadcast_to(packed["dang"], (p, n_pad)).copy(),
+                sh("ue", None))
+            vblk = jax.device_put(packed["vblk"].astype(np_dtype),
+                                  sh("ue", None, None))
+            x0_dev = jax.device_put(x0_blocks, sh("ue", None, None))
+            if use_bsr:
+                op_args = tuple(
+                    jax.device_put(packed[k], sh("ue", *([None] * nd)))
+                    for k, nd in (("blk", 4), ("bcols", 2), ("hrow", 1),
+                                  ("hcol", 1), ("hval", 1)))
+            else:
+                op_args = tuple(jax.device_put(packed[k], sh("ue", None))
+                                for k in ("src", "wgt", "rid"))
 
         accum = self.accum
 
-        def body_fn(vblk, valid, dang, x0, *op_args):
+        # the program's name is what a profile shows (`jit_...`); the
+        # named scopes tell its while loop from the final delta there
+        def device_shard_drain(vblk, valid, dang, x0, *op_args):
             vb_, val_, dg_, myx = vblk[0], valid[0], dang[0], x0[0]
             i = jax.lax.axis_index("ue")
             op_slice = tuple(a[0] for a in op_args)
@@ -247,19 +262,21 @@ class DeviceShardTransport:
 
             carry = _step.init_carry(myx, init_comm, nv=1, n_pad=n_pad,
                                      axis="ue")
-            (view, frag, _, step, pc, mon_pc, lane_done, lane_step,
-             rows_sent, fulls) = jax.lax.while_loop(
-                cond, lambda c: superstep(c), carry)
+            with jax.named_scope("drain_loop"):
+                (view, frag, _, step, pc, mon_pc, lane_done, lane_step,
+                 rows_sent, fulls) = jax.lax.while_loop(
+                    cond, lambda c: superstep(c), carry)
             # final device-visible delta L1 (telemetry only — the caller
             # certifies with the host-side exact residual)
             from . import transport as _transport
-            dl1 = _transport.mesh_psum("ue")(
-                jnp.sum(jnp.abs(local_update(view) - frag)))
+            with jax.named_scope("final_delta"):
+                dl1 = _transport.mesh_psum("ue")(
+                    jnp.sum(jnp.abs(local_update(view) - frag)))
             return (frag[None], step[None], dl1[None],
                     lane_done[None], rows_sent[None], fulls[None])
 
         mapped = jax.shard_map(
-            body_fn, mesh=mesh,
+            device_shard_drain, mesh=mesh,
             in_specs=(P("ue", None, None), P("ue", None), P("ue", None),
                       P("ue", None, None))
             + tuple(P("ue", *([None] * (a.ndim - 1))) for a in op_args),
@@ -267,17 +284,20 @@ class DeviceShardTransport:
                        P("ue", None), P("ue"), P("ue")),
             check_vma=False,
         )
-        frags, steps, dl1, lane_done, rows_sent, fulls = \
-            jax.jit(mapped)(vblk, valid, dang, x0_dev, *op_args)
+        with span("transport.dispatch", into=phase_s):
+            outs = jax.jit(mapped)(vblk, valid, dang, x0_dev, *op_args)
+        with span("transport.fetch", into=phase_s):
+            frag_mat, steps, dl1, lane_done, rows_sent, fulls = (
+                np.asarray(a) for a in outs)
 
-        frag_mat = np.asarray(frags, dtype=np.float64)
-        supersteps = int(np.asarray(steps).max())
+        frag_mat = frag_mat.astype(np.float64, copy=False)
+        supersteps = int(steps.max())
         x = np.empty(n, dtype=np.float64)
         for i in range(p):
             s, t = part.block(i)
             x[s:t] = frag_mat[i, : t - s, 0]
-        rows_total = int(np.asarray(rows_sent).sum())
-        fulls_total = int(np.asarray(fulls).sum())
+        rows_total = int(rows_sent.sum())
+        fulls_total = int(fulls.sum())
         comm_total = _step.comm_bytes_model(
             self.exchange, p=p, bsize=bsize, itemsize=np_dtype.itemsize,
             nv=1, steps=supersteps, rows=rows_total, fulls=fulls_total,
@@ -285,7 +305,7 @@ class DeviceShardTransport:
         return DeviceRunResult(
             x=x, supersteps=supersteps, rows_sent=rows_total,
             fulls=fulls_total, comm_bytes_total=comm_total,
-            device_resid=float(np.asarray(dl1)[0]),
-            converged=bool(np.asarray(lane_done).all()),
+            device_resid=float(dl1[0]),
+            converged=bool(lane_done.all()),
             p=p, schedule=self.exchange,
-            devices=len(frags.sharding.device_set))
+            devices=len(outs[0].sharding.device_set))

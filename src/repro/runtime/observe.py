@@ -1,10 +1,21 @@
 """Unified runtime observability: metrics registry, event tracing,
-push-inflation attribution.
+push-inflation attribution, program spans.
 
-Three pieces, all built on plain numpy arrays so the *same* code runs
-over in-process arrays (threads transport) and over `ShardArena` views
-(procpool transport, where worker-written slots must survive the
-process boundary and supervisor respawns):
+Two instruments, one for each kind of seam:
+
+  * **rings** (`ShardObserver`) record the host workers' eq. (5) cycle
+    seams, thousands a second, on `time.perf_counter`, into numpy slots
+    that survive the procpool's process boundary;
+  * **spans** (`span`) mark the layer seams of a chip path (serving,
+    update, device transport, certify, solver), a handful a call, as
+    `jax.profiler.TraceAnnotation`s: in a profiler trace they sit in the
+    same XPlane as the device's operations, on the same clock, so device
+    idle time can be put under the layer that left the chip idle.
+
+The rings come in three pieces, all built on plain numpy arrays so the
+*same* code runs over in-process arrays (threads transport) and over
+`ShardArena` views (procpool transport, where worker-written slots must
+survive the process boundary and supervisor respawns):
 
   * a lock-cheap **metrics registry** — a fixed schema of per-shard
     counter slots (`OBS_COUNTERS`) plus one fixed-bucket histogram
@@ -40,13 +51,15 @@ process boundary and supervisor respawns):
 Everything is **zero-cost when off**: the observer default is `None`
 and every hook is behind an `if obs is not None` — no registry object,
 no ring allocation, no arena slots (the control-arena spec only grows
-when observing).
+when observing).  A span with the profiler off costs one `TraceMe`
+check and two `perf_counter` reads.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -70,20 +83,38 @@ EV_RECOVERY = 9   # supervisor recovery (a = pool slot / worker,
                   # b = exitcode, c = restored-from-checkpoint (0/1);
                   # dur = detection -> recovered seconds)
 EV_CAPPED = 10    # push budget hit (a = round)
-EV_CHUNK = 11     # SPMD compact-lanes chunk (a = lanes, b = steps,
-                  # c = rows, d = bytes)
 
 EV_NAMES = {
     EV_INTAKE: "INTAKE", EV_DRAIN: "DRAIN", EV_EXCHANGE: "EXCHANGE",
     EV_CONVERGE: "CONVERGE", EV_DIVERGE: "DIVERGE", EV_STOP: "STOP",
     EV_KILL: "KILL", EV_HANG: "HANG", EV_RECOVERY: "RECOVERY",
-    EV_CAPPED: "CAPPED", EV_CHUNK: "CHUNK",
+    EV_CAPPED: "CAPPED",
 }
 
 # events rendered as Chrome "X" (complete, with duration) vs "i" (instant)
 _EV_SPAN = (EV_INTAKE, EV_DRAIN, EV_EXCHANGE)
 
 DEFAULT_EVENT_CAP = 2048
+
+# ---------------------------------------------------------------------------
+# program spans (layer seams of the chip paths)
+# ---------------------------------------------------------------------------
+@contextlib.contextmanager
+def span(name: str, into: Optional[Dict[str, float]] = None
+         ) -> Iterator[None]:
+    """Mark `name` (`<layer>.<step>`) as a `jax.profiler.TraceAnnotation`
+    and, when `into` is a dict, add the span's wall seconds to
+    `into[name]`.  jax is imported here, not at module import, so the
+    procpool worker path imports nothing new."""
+    from jax.profiler import TraceAnnotation
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        if into is not None:
+            into[name] = into.get(name, 0.0) + time.perf_counter() - t0
+
 
 # ---------------------------------------------------------------------------
 # metrics registry schema (per-shard counter slots, single writer per row)

@@ -37,7 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..runtime.observe import render_prometheus
+from ..runtime.observe import render_prometheus, span
 from ..runtime.schedule import make_schedule
 from .delta import DeltaGraph, EdgeDelta, FrozenGraphView, merge_deltas
 from .incremental import (RankState, UpdateStats, _exact_residual,
@@ -208,6 +208,9 @@ class RankServer:
         self.state_recoveries = 0   # _recover_state entries (any path)
         self.cold_rebuilds = 0      # ...that took the cold_state resort
         self.last_stats = None   # UpdateStats | ShardedUpdateStats
+        # wall seconds per program span over every applied batch
+        # (`serving.*` here, the sharded updater's `stats.phase_s`)
+        self._phase_s: Dict[str, float] = {}
 
         # degrade-gracefully state (PR 6): a daemon-updater failure no
         # longer dies silently — it is captured here, the working state is
@@ -291,64 +294,84 @@ class RankServer:
     def apply_pending(self) -> Optional[UpdateStats]:
         """Drain the queue, apply one merged batch, publish. Inline and
         deterministic (the non-threaded mode); returns the update stats or
-        None when the queue was empty."""
+        None when the queue was empty.
+
+        The whole call is the program span `serving.apply`, the publish
+        `serving.publish`; their seconds, and those of the sharded
+        updater's spans, add up in `metrics()["phase_s"]`."""
         with self._lock:
-            batch = self._drain()
-            if not batch:
-                return None
-            merged = merge_deltas(batch)
-            ver0 = self.dg.version
-            try:
-                if self.updater == "sharded":
-                    self._state, stats = update_ranks_sharded(
-                        self.dg, merged, self._state, tol=self.tol,
-                        p=self.shards, exchange=self.exchange,
-                        mode=self.shard_mode,
-                        transport=self.shard_transport,
-                        n_workers=self.shard_workers,
-                        backend=self.backend, method=self.method,
-                        schedule=self.drain_schedule)
-                else:
-                    self._state, stats = update_ranks(
-                        self.dg, merged, self._state, tol=self.tol,
-                        backend=self.backend, method=self.method,
-                        push_frontier_frac=self.push_frontier_frac,
-                        schedule=self.drain_schedule)
-            except BaseException:
-                # the batch is only safe to retry when the graph did NOT
-                # advance (a failure after dg.apply means the delta is
-                # already in the graph — re-enqueueing would double-apply
-                # it); a bounded retry budget keeps a poisoned batch from
-                # cycling forever
-                if self.dg.version == ver0 and self._requeue_budget > 0:
-                    self._requeue_budget -= 1
-                    self._queue.put(merged)
-                raise
-            self._requeue_budget = self._REQUEUE_CAP
-            fell_back = stats.path not in ("push", "sharded_push")
-            self._batches_since_refresh += 1
-            if fell_back:
-                self._batches_since_refresh = 0
-            elif self._batches_since_refresh >= self.refresh_every:
-                # long pure-push chains re-derive the residual exactly so
-                # float drift never silently erodes the certificate
-                refresh_residual(self.dg, self._state)
-                self._batches_since_refresh = 0
-            # all telemetry lives under _stat_lock (concurrent query
-            # threads read these counters; _lock only serializes updaters)
-            with self._stat_lock:
-                self.batches_applied += 1
-                if fell_back:
-                    self.fallbacks += 1
-                self.last_stats = stats
-            cache = self._ppr_cache
-            if cache is not None:
-                # advance the cache's certified drift accounting BEFORE
-                # publishing, so a query against the new snapshot can
-                # already hit entries whose bound survived this delta
-                cache.note_update(self.dg._last_receipt)
-            self._cut_snapshot()
+            phase_s: Dict[str, float] = {}
+            with span("serving.apply", into=phase_s):
+                stats = self._apply_batch(phase_s)
+            if stats is not None:
+                with self._stat_lock:
+                    for k, v in phase_s.items():
+                        self._phase_s[k] = self._phase_s.get(k, 0.0) + v
             return stats
+
+    def _apply_batch(self, phase_s: Dict[str, float]
+                     ) -> Optional[UpdateStats]:
+        """apply_pending's body, under `_lock`: the span seconds of the
+        batch go to `phase_s`."""
+        batch = self._drain()
+        if not batch:
+            return None
+        merged = merge_deltas(batch)
+        ver0 = self.dg.version
+        try:
+            if self.updater == "sharded":
+                self._state, stats = update_ranks_sharded(
+                    self.dg, merged, self._state, tol=self.tol,
+                    p=self.shards, exchange=self.exchange,
+                    mode=self.shard_mode,
+                    transport=self.shard_transport,
+                    n_workers=self.shard_workers,
+                    backend=self.backend, method=self.method,
+                    schedule=self.drain_schedule)
+            else:
+                self._state, stats = update_ranks(
+                    self.dg, merged, self._state, tol=self.tol,
+                    backend=self.backend, method=self.method,
+                    push_frontier_frac=self.push_frontier_frac,
+                    schedule=self.drain_schedule)
+        except BaseException:
+            # the batch is only safe to retry when the graph did NOT
+            # advance (a failure after dg.apply means the delta is
+            # already in the graph — re-enqueueing would double-apply
+            # it); a bounded retry budget keeps a poisoned batch from
+            # cycling forever
+            if self.dg.version == ver0 and self._requeue_budget > 0:
+                self._requeue_budget -= 1
+                self._queue.put(merged)
+            raise
+        self._requeue_budget = self._REQUEUE_CAP
+        fell_back = stats.path not in ("push", "sharded_push")
+        self._batches_since_refresh += 1
+        if fell_back:
+            self._batches_since_refresh = 0
+        elif self._batches_since_refresh >= self.refresh_every:
+            # long pure-push chains re-derive the residual exactly so
+            # float drift never silently erodes the certificate
+            refresh_residual(self.dg, self._state)
+            self._batches_since_refresh = 0
+        # all telemetry lives under _stat_lock (concurrent query
+        # threads read these counters; _lock only serializes updaters)
+        with self._stat_lock:
+            self.batches_applied += 1
+            if fell_back:
+                self.fallbacks += 1
+            self.last_stats = stats
+        cache = self._ppr_cache
+        if cache is not None:
+            # advance the cache's certified drift accounting BEFORE
+            # publishing, so a query against the new snapshot can
+            # already hit entries whose bound survived this delta
+            cache.note_update(self.dg._last_receipt)
+        if isinstance(stats, ShardedUpdateStats):
+            phase_s.update(stats.phase_s)
+        with span("serving.publish", into=phase_s):
+            self._cut_snapshot()
+        return stats
 
     # ------------------------------------------------------------------
     # async updater (update-while-serve)
@@ -502,6 +525,7 @@ class RankServer:
                 cold_rebuilds=int(self.cold_rebuilds),
                 consecutive_failures=int(self.consecutive_failures),
                 updater_restarts=int(self.updater_restarts),
+                phase_s=dict(self._phase_s),
             )
         m.update(
             updater_started=started, updater_alive=alive,
@@ -523,6 +547,9 @@ class RankServer:
             "consecutive_failures", "snapshot_seq", "snapshot_cert",
             "version_lag", "pending_deltas", "snapshot_age_s",
             "updater_alive")]
+        fams.append(("phase_seconds_total", "counter",
+                     {(("phase", k),): v
+                      for k, v in sorted(m["phase_s"].items())}))
         return render_prometheus(fams, prefix="repro_rank_server")
 
     def stop(self, drain: bool = True, timeout: float = 30.0) -> None:

@@ -55,7 +55,7 @@ from ..runtime.driver import TerminationDriver
 from ..runtime.exchange import AllToAllPlan, ExchangePlan, SparsifiedPlan
 from ..runtime.executor import AsyncShardExecutor
 from ..runtime.faults import FaultPlan
-from ..runtime.observe import ShardObserver, attribute_frontier
+from ..runtime.observe import ShardObserver, attribute_frontier, span
 from ..runtime.schedule import ScheduleSpec, make_schedule
 from ..runtime.state import ShardArena
 from ..runtime.transport import ProcPoolShardExecutor
@@ -104,6 +104,11 @@ class ShardedUpdateStats:
     device_resid: float = 0.0  # final device-visible delta L1 (telemetry;
     #                          # the published cert is the exact recompute)
     devices: int = 0           # devices the shard program's output spans
+    # wall seconds per program span of this update (`runtime.observe.
+    # span`): `update.apply_delta` on every path; the device transport
+    # adds `transport.operator` / `.pack` / `.dispatch` / `.fetch` and
+    # `certify.exact_residual`, summed over its attempts
+    phase_s: dict = dataclasses.field(default_factory=dict)
 
 
 def _scatter_add(out: np.ndarray, idx: np.ndarray,
@@ -325,8 +330,8 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
                    seed_l1: float, sparsify_thresh: Optional[float],
                    sparsify_refresh_every: int, pc_max_compute: int,
                    pc_max_monitor: int, max_supersteps: int, backend: str,
-                   method: str, solver_max_iters: int, schedule_name: str
-                   ) -> Tuple[RankState, ShardedUpdateStats]:
+                   method: str, solver_max_iters: int, schedule_name: str,
+                   phase_s: dict) -> Tuple[RankState, ShardedUpdateStats]:
     """The device-transport drain: warm-start the linear form (eq. 7) from
     the current iterate as p shard programs (runtime/device.py), then
     certify with the host-side exact recompute.
@@ -346,7 +351,8 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
                          if sparsify_thresh is not None else 0.0),
         sparsify_refresh_every=sparsify_refresh_every,
         pc_max_compute=pc_max_compute, pc_max_monitor=pc_max_monitor)
-    op = dg.operator(alpha, v=state.v)
+    with span("transport.operator", into=phase_s):
+        op = dg.operator(alpha, v=state.v)
     target = 0.5 * l1_target
     supersteps = rows = fulls = 0
     bytes_total = 0
@@ -356,7 +362,8 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
     resid = float(np.abs(r).sum())
     while (attempts == 0 or resid > l1_target) and attempts < 4:
         attempts += 1
-        res = dev.run(op, x, target=target, max_supersteps=max_supersteps)
+        res = dev.run(op, x, target=target, max_supersteps=max_supersteps,
+                      phase_s=phase_s)
         x[:] = res.x
         supersteps += res.supersteps
         rows += res.rows_sent
@@ -367,7 +374,8 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
         # re-derive the maintained residual exactly from the new iterate
         # (one O(nnz) host apply) — both the re-entry decision and the
         # published certificate stand on it
-        r[:] = _exact_residual(dg, x, alpha, state.v)
+        with span("certify.exact_residual", into=phase_s):
+            r[:] = _exact_residual(dg, x, alpha, state.v)
         resid = float(np.abs(r).sum())
         target *= 0.25
     pps = np.zeros(p, dtype=np.int64)
@@ -379,7 +387,7 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
             cert=resid / (1.0 - alpha), stop_superstep=supersteps,
             mode="async", attempts=attempts, transport="device",
             rows_sent=rows, fulls=fulls, device_resid=device_resid,
-            schedule=schedule_name, devices=devices)
+            schedule=schedule_name, devices=devices, phase_s=phase_s)
     return _solver_fallback(
         dg, state, alpha=alpha, tol=tol, method=method, backend=backend,
         solver_max_iters=solver_max_iters,
@@ -389,7 +397,7 @@ def _device_update(dg: DeltaGraph, state: RankState, *, p: int,
                       mode="async", attempts=max(attempts, 1),
                       transport="device", rows_sent=rows, fulls=fulls,
                       device_resid=device_resid, schedule=schedule_name,
-                      devices=devices))
+                      devices=devices, phase_s=phase_s))
 
 
 def update_ranks_sharded(
@@ -425,7 +433,8 @@ def update_ranks_sharded(
     ``XLA_FLAGS=--xla_force_host_platform_device_count=p``.  Faults,
     observe and custom drain schedules are host-seam features and
     raise; the device counters land on ``stats.rows_sent`` /
-    ``stats.fulls`` / ``stats.bytes_moved``).  On success
+    ``stats.fulls`` / ``stats.bytes_moved``, and the seconds of its
+    program spans on ``stats.phase_s``).  On success
     ``stats.cert`` is sound and ``state.cert <= stats.cert`` (state.r is
     the exactly-maintained residual; the superstep bound is the driver's
     all-reduced sum, the async bound is the exact post-fold recompute —
@@ -491,16 +500,18 @@ def update_ranks_sharded(
                          "superstep loop has no worker cycle to trace)")
     if transport == "device":
         # the device rendering is a pure jax program: no worker seam to
-        # inject faults at or trace, and drain scheduling is the traced
-        # step itself (observe counters roll in host-side, from the
-        # program's own (rows, fulls) outputs)
+        # inject faults at or ring-trace, and drain scheduling is the
+        # traced step itself (its counters roll in host-side, from the
+        # program's own (rows, fulls) outputs; its layer seams are
+        # program spans, timed in stats.phase_s)
         if faulty:
             raise ValueError("faults= is not supported on "
                              "transport='device' (no host worker seam)")
         if observe:
             raise ValueError("observe=True is not supported on "
                              "transport='device'; the device counters "
-                             "(rows_sent/fulls/bytes) land on the stats")
+                             "(rows_sent/fulls/bytes) land on the stats "
+                             "and its spans' seconds in stats.phase_s")
     spec = make_schedule(schedule)
     if transport == "device" and spec.name != "default":
         raise ValueError("schedule= renderings are host-drain heuristics; "
@@ -513,16 +524,19 @@ def update_ranks_sharded(
             "node arrivals with a custom teleport vector are not "
             "supported incrementally; rebuild via cold_state")
     alpha = state.alpha
-    rcpt = dg.apply(delta)
-    c = _seed_delta(dg, rcpt, state)
-    x, r = state.x, state.r
-    n = rcpt.n_new
-    seed_l1 = float(np.abs(r).sum()) + abs(c) * n
+    phase_s: dict = {}
+    with span("update.apply_delta", into=phase_s):
+        rcpt = dg.apply(delta)
+        c = _seed_delta(dg, rcpt, state)
+        x, r = state.x, state.r
+        n = rcpt.n_new
+        seed_l1 = float(np.abs(r).sum()) + abs(c) * n
 
-    # the sharded drain keeps no per-shard rescale state, so the uniform
-    # component folds densely up front (exact; O(n) once per batch)
-    if c != 0.0:
-        r += c
+        # the sharded drain keeps no per-shard rescale state, so the
+        # uniform component folds densely up front (exact; O(n) once per
+        # batch)
+        if c != 0.0:
+            r += c
 
     part = block_rows(n, p)
     l1_target = (1.0 - alpha) * tol
@@ -541,7 +555,8 @@ def update_ranks_sharded(
             sparsify_refresh_every=sparsify_refresh_every,
             pc_max_compute=pc_max_compute, pc_max_monitor=pc_max_monitor,
             max_supersteps=max_supersteps, backend=backend, method=method,
-            solver_max_iters=solver_max_iters, schedule_name=spec.name)
+            solver_max_iters=solver_max_iters, schedule_name=spec.name,
+            phase_s=phase_s)
 
     arrays = _view_arrays(dg)
 
@@ -704,7 +719,7 @@ def update_ranks_sharded(
                 recovery_s=recovery_s, pushes_first=int(attr_tot[0]),
                 pushes_local=int(attr_tot[1]),
                 pushes_boundary=int(attr_tot[2]), observed=observed,
-                schedule=spec.name)
+                schedule=spec.name, phase_s=phase_s)
         return _solver_fallback(
             dg, state, alpha=alpha, tol=tol, method=method,
             backend=backend, solver_max_iters=solver_max_iters,
@@ -717,7 +732,8 @@ def update_ranks_sharded(
                           pushes_first=int(attr_tot[0]),
                           pushes_local=int(attr_tot[1]),
                           pushes_boundary=int(attr_tot[2]),
-                          observed=observed, schedule=spec.name))
+                          observed=observed, schedule=spec.name,
+                          phase_s=phase_s))
 
     local_target = l1_target / (2.0 * p)
     plan = _make_plan(exchange, p, l1_target, sparsify_thresh,
@@ -810,7 +826,7 @@ def update_ranks_sharded(
             pushes_per_shard=pushes_per_shard, exchanges=exchanges,
             bytes_moved=bytes_moved, seed_l1=seed_l1, resid_l1=total,
             cert=total / (1.0 - alpha), stop_superstep=stop_superstep,
-            schedule=spec.name)
+            schedule=spec.name, phase_s=phase_s)
 
     return _solver_fallback(
         dg, state, alpha=alpha, tol=tol, method=method, backend=backend,
@@ -818,7 +834,8 @@ def update_ranks_sharded(
         stats_kw=dict(p=p, supersteps=step, pushes=pushes,
                       pushes_per_shard=pushes_per_shard,
                       exchanges=exchanges, bytes_moved=bytes_moved,
-                      seed_l1=seed_l1, schedule=spec.name))
+                      seed_l1=seed_l1, schedule=spec.name,
+                      phase_s=phase_s))
 
 
 def _solver_fallback(dg: DeltaGraph, state: RankState, *, alpha: float,

@@ -176,17 +176,15 @@ def _check_causal(events):
 
 @pytest.mark.parametrize("transport", ["threads", "procpool"])
 def test_trace_and_attribution_sound(transport):
-    # under suite-level CPU contention the async drain can legitimately
-    # exhaust its 2x push budget and take the solver fallback; rebuild
-    # the workload (dg.apply mutates the graph) and retry the
-    # timing-dependent run rather than assert on one sample
-    for _ in range(3):
-        dg, delta, st = _small_workload(n=2000, seed=7, k=20)
-        st, stats = update_ranks_sharded(dg, delta, st, p=4, tol=1e-8,
-                                         mode="async", transport=transport,
-                                         observe=True)
-        if stats.path == "sharded_push":
-            break
+    # the async drain's push count depends on how the host interleaves
+    # the workers: 110k-210k pops here, against the default budget of
+    # 2 x 40 x n = 160k, so a loaded host took the solver fallback. This
+    # test is about the trace, not the budget: 400 x n puts the budget
+    # far above any interleaving, so the drain always certifies
+    dg, delta, st = _small_workload(n=2000, seed=7, k=20)
+    st, stats = update_ranks_sharded(dg, delta, st, p=4, tol=1e-8,
+                                     mode="async", transport=transport,
+                                     observe=True, max_push_factor=400.0)
     assert stats.path == "sharded_push"
     obs = stats.observed
     assert obs is not None
