@@ -14,20 +14,22 @@ and every solver in this repo funnels through it. Two backends implement it:
                 kernel on TPU, the identical blocked-einsum contraction
                 under XLA elsewhere — and the in-degree-tail rows go through
                 a fused segment-sum side path. The iterate stays resident in
-                the padded (nbr, bm, nv) block layout across the whole
-                while_loop; nothing is repacked between iterations, and nv
-                teleport lanes share every block load (batched personalized
-                PageRank).
+                the padded, lane-dense (nv, nbr, bm) block layout across the
+                whole while_loop; nothing is repacked between iterations,
+                and nv teleport lanes share every block load (batched
+                personalized PageRank).
 
 A backend is addressed by a hashable BackendSpec so the fused solver loop
 can jit once per (spec, shapes) and dispatch statically.
 
 Layout contract (bsr_pallas):
   * square blocks (bm == bn) so y has the same layout as x and the loop
-    never leaves (nbr, bm, nv);
+    never leaves (nv, nbr, bm): lanes outermost, the block edge on the
+    lane axis, so for nv = 1 the iterate is a compact (nbr, bm) array;
   * padded rows/cols beyond n are exactly zero and stay zero: blocks and
     the hub COO never touch them, the teleport vector and the scalar
-    dangling-mass correction are masked by `valid`;
+    dangling-mass correction are masked by `valid` ((1, nbr, bm), as is
+    `dang`);
   * arithmetic is float32 (the MXU accumulates in f32) — L1 residuals
     bottom out around 1e-7; ask segment_sum/float64 for tighter tolerances.
 """
@@ -42,7 +44,7 @@ import jax.numpy as jnp
 
 from ..graph.google import GoogleOperator
 from ..graph.csr import pt_matvec
-from ..kernels.bsr_spmv import hybrid_matvec, pad_x
+from ..kernels.bsr_spmv import hybrid_matvec, pad_x, unpad_y
 
 BACKENDS = ("segment_sum", "bsr_pallas")
 
@@ -193,13 +195,10 @@ def prepare(op: GoogleOperator, spec: BackendSpec, dtype,
     dev_struct = cache.get(key)
     if dev_struct is None:
         dev_struct = hyb.device()
-        nbr = hyb.bsr.nbr
-        valid = np.zeros((nbr * bm, 1), dtype=np.float32)
-        valid[:n] = 1.0
-        dang = np.zeros((nbr * bm, 1), dtype=np.float32)
-        dang[:n, 0] = op.pt.dangling.astype(np.float32)
-        dev_struct["valid"] = jnp.asarray(valid.reshape(nbr, bm, 1))
-        dev_struct["dang"] = jnp.asarray(dang.reshape(nbr, bm, 1))
+        valid = np.ones(n, dtype=np.float32)
+        dang = op.pt.dangling.astype(np.float32)
+        dev_struct["valid"] = jnp.asarray(pad_x(valid, n, bm))
+        dev_struct["dang"] = jnp.asarray(pad_x(dang, n, bm))
         cache[key] = dev_struct
     dev = dict(dev_struct)
     nbr = hyb.bsr.nbr
@@ -215,7 +214,7 @@ def from_layout(meta: BackendMeta, x_dev) -> np.ndarray:
     x = np.asarray(x_dev, dtype=np.float64)
     if meta.spec.name == "segment_sum":
         return x
-    return x.reshape(meta.n_pad, meta.nv)[:meta.n]
+    return unpad_y(x, meta.n)
 
 
 # --------------------------------------------------------------------------
@@ -236,23 +235,30 @@ def google_apply(meta: BackendMeta, dev: dict, x: jax.Array,
             y = y + (1.0 - alpha) * jnp.sum(x, axis=0)[None, :] * dev["v"]
         return y
 
-    # bsr_pallas: x is (nbr, bm, nv)
+    # bsr_pallas: x is (nv, nbr, bm)
     y = alpha * hybrid_matvec(dev, x, impl=meta.spec.impl)
-    dmass = jnp.sum(x * dev["dang"], axis=(0, 1))          # (nv,)
-    y = y + (alpha / n) * dmass[None, None, :] * dev["valid"]
+    dmass = jnp.sum(x * dev["dang"], axis=(1, 2))          # (nv,)
+    y = y + (alpha / n) * dmass[:, None, None] * dev["valid"]
     if linear:
         y = y + (1.0 - alpha) * dev["v"]
     else:
-        s = jnp.sum(x * dev["valid"], axis=(0, 1))         # (nv,)
-        y = y + (1.0 - alpha) * s[None, None, :] * dev["v"]
+        s = jnp.sum(x * dev["valid"], axis=(1, 2))         # (nv,)
+        y = y + (1.0 - alpha) * s[:, None, None] * dev["v"]
     return y.astype(x.dtype)
 
 
-def l1_residual(y: jax.Array, x: jax.Array) -> jax.Array:
+def lane_axis(meta: BackendMeta) -> int:
+    """The lane axis of the backend layout: last for segment_sum's
+    (n, nv), first for bsr_pallas's (nv, nbr, bm)."""
+    return -1 if meta.spec.name == "segment_sum" else 0
+
+
+def l1_residual(meta: BackendMeta, y: jax.Array, x: jax.Array) -> jax.Array:
     """Per-lane L1 residual ||y - x||_1, shape (nv,). Padding rows are zero
     in both layouts so no masking is needed."""
     d = jnp.abs(y - x)
-    return jnp.sum(d, axis=tuple(range(d.ndim - 1)))
+    ax = lane_axis(meta) % d.ndim
+    return jnp.sum(d, axis=tuple(a for a in range(d.ndim) if a != ax))
 
 
 def take_lanes(meta: BackendMeta, dev: dict, x: jax.Array,
@@ -265,7 +271,8 @@ def take_lanes(meta: BackendMeta, dev: dict, x: jax.Array,
     device state (edges, blocks, masks) is lane-invariant and shared.
     """
     idx = np.asarray(idx, dtype=np.int64)
+    ax = lane_axis(meta)
     dev = dict(dev)
-    dev["v"] = dev["v"][..., idx]
+    dev["v"] = jnp.take(dev["v"], idx, axis=ax)
     meta = dataclasses.replace(meta, nv=int(idx.size))
-    return dev, meta, x[..., idx]
+    return dev, meta, jnp.take(x, idx, axis=ax)

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..graph.google import GoogleOperator
-from ..runtime.observe import span
+from ..runtime.observe import kernel_counters, span
 from .backend import (BackendSpec, BackendMeta, as_lane_tol, as_spec,
                       prepare, from_layout, google_apply, l1_residual,
                       take_lanes)
@@ -36,13 +36,15 @@ class SolveResult:
     resid_per_vec: Optional[np.ndarray] = None  # (nv,) when nv > 1
     lane_iters: Optional[np.ndarray] = None     # (nv,) iterations per lane
                                                 # (differs under freezing)
+    kernel: Optional[dict] = None  # bsr_pallas: runtime.observe's
+                                   # kernel_counters of the packed layout
 
 
 @partial(jax.jit, static_argnames=("meta", "linear", "max_iters"))
 def _solve_jit(dev: dict, x0: jax.Array, tol: jax.Array, *,
                meta: BackendMeta, linear: bool, max_iters: int):
     """Fused fixed-point loop: the iterate never leaves the backend layout
-    (for bsr_pallas that is the padded (nbr, bm, nv) block layout — no
+    (for bsr_pallas that is the padded (nv, nbr, bm) block layout — no
     repacking between iterations).  `tol` is a traced (nv,) per-lane
     residual threshold (mixed-tol query batches share one compiled loop;
     a scalar tol also no longer triggers a recompile per value)."""
@@ -53,7 +55,7 @@ def _solve_jit(dev: dict, x0: jax.Array, tol: jax.Array, *,
     def body(state):
         x, _, it = state
         y = google_apply(meta, dev, x, linear)
-        resid = l1_residual(y, x)
+        resid = l1_residual(meta, y, x)
         return y, resid, it + 1
 
     resid0 = jnp.full((meta.nv,), jnp.inf, x0.dtype)
@@ -289,9 +291,13 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
     nv = x.shape[1]
     if squeeze and nv == 1:
         x = x[:, 0]
+    kernel = None
+    if spec.name == "bsr_pallas":
+        kernel = kernel_counters(op.hybrid_bsr(
+            bm=spec.bm, bn=spec.bm, hub_quantile=spec.hub_quantile).bsr, nv)
     return SolveResult(x=x, iters=int(iters), resid_l1=float(resid.max()),
                        resid_per_vec=resid if nv > 1 else None,
-                       lane_iters=lane_iters)
+                       lane_iters=lane_iters, kernel=kernel)
 
 
 def rank_of(x: np.ndarray) -> np.ndarray:

@@ -236,8 +236,6 @@ def _pack_blocks(op: GoogleOperator, part: Partition, dtype,
         hmax = max(1, max(len(sh["h_rows"]) for sh in shard))
 
         nbr_l = bsize // bm
-        blk = np.zeros((p, nbr_l, K, bm, bm), dtype=np.float32)
-        bcols = np.zeros((p, nbr_l, K), dtype=np.int32)
         hrow = np.zeros((p, hmax), dtype=np.int32)
         hcol = np.zeros((p, hmax), dtype=np.int32)
         hval = np.zeros((p, hmax), dtype=np.float32)
@@ -246,15 +244,23 @@ def _pack_blocks(op: GoogleOperator, part: Partition, dtype,
             b = build_bsr(sh["rows"], sh["cols"], sh["vals"],
                           n_rows=bsize, n_cols=n_pad, bm=bm, bn=bm,
                           k_budget=K, unique_pairs=True)
+            if i == 0:
+                # build_bsr pads the shared K to a multiple of the
+                # kernel's group size, alike for every shard
+                blk = np.zeros((p, nbr_l, b.K, bm, bm), dtype=np.float32)
+                bcols = np.zeros((p, nbr_l, b.K), dtype=np.int32)
+                rgroups = np.zeros((p, nbr_l), dtype=np.int32)
             blk[i] = b.blocks
             bcols[i] = b.blk_cols
+            rgroups[i] = b.row_groups
             e = len(sh["h_rows"])
             hrow[i, :e] = sh["h_rows"]
             hcol[i, :e] = sh["h_cols"]
             hval[i, :e] = sh["h_vals"]
             fills.append(b.fill_ratio)
-        packed.update(blk=blk, bcols=bcols, hrow=hrow, hcol=hcol, hval=hval,
-                      K=K, bm=bm, fill_ratio=float(np.mean(fills)))
+        packed.update(blk=blk, bcols=bcols, rgroups=rgroups, hrow=hrow,
+                      hcol=hcol, hval=hval, K=blk.shape[2], bm=bm,
+                      fill_ratio=float(np.mean(fills)))
     else:
         emax = max(b["src"].shape[0] for b in blocks)
         src = np.zeros((p, emax), dtype=np.int32)
@@ -350,7 +356,8 @@ def solve_spmd(op: GoogleOperator, cfg: SPMDConfig,
 
     if use_bsr:
         op_args = tuple(jax.device_put(packed[k], sh("ue", *([None] * nd)))
-                        for k, nd in (("blk", 4), ("bcols", 2), ("hrow", 1),
+                        for k, nd in (("blk", 4), ("bcols", 2),
+                                      ("rgroups", 1), ("hrow", 1),
                                       ("hcol", 1), ("hval", 1)))
     else:
         op_args = tuple(jax.device_put(packed[k], sh("ue", None))

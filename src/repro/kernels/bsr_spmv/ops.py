@@ -22,21 +22,27 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .bsr_spmv import bsr_spmv, DEFAULT_BM, DEFAULT_BN
+from .bsr_spmv import (bsr_spmv, group_size, row_chunks, DEFAULT_BM,
+                       DEFAULT_BN)
 from .ref import bsr_spmv_ref
 from ...graph.csr import TransitionT
 
 # the dense-block array is uploaded whole to one device, so refuse to
-# pack one that cannot be solved on a v5e chip: 16 GB of HBM less the
-# fused power loop's ~0.93 GB of temporaries at the Stanford-Web shape
-# (AOT memory_analysis for v5e). Packing happens on the host, so this is
-# also the host memory a pack may take on any platform
+# pack one that cannot be solved on a v5e chip: 16 GB of HBM less 1 GB
+# for the rest of the fused power loop (hub arrays, iterates; AOT
+# memory_analysis for v5e finds no temporaries at the Stanford-Web shape).
+# Packing happens on the host, so this is also the host memory a pack may
+# take on any platform
 MAX_BLOCK_BYTES = 15 * 10**9
 
 
 @dataclasses.dataclass(frozen=True)
 class BSRMatrix:
-    """Host container: block-CSR with a fixed blocks-per-row budget."""
+    """Host container: block-CSR with a fixed blocks-per-row budget.
+
+    K is a multiple of the kernel's group size; a row's stored blocks sit
+    in slots 0..count-1, and `row_groups` counts the groups that hold
+    them (the kernel's ragged stop)."""
     n_rows: int                 # logical (unpadded) rows
     n_cols: int
     bm: int
@@ -44,6 +50,7 @@ class BSRMatrix:
     blocks: np.ndarray          # (nbr, K, bm, bn) float32
     blk_cols: np.ndarray        # (nbr, K) int32
     fill_ratio: float           # nnz / dense-block capacity actually used
+    row_groups: np.ndarray      # (nbr,) int32 groups of stored blocks
 
     @property
     def nbr(self) -> int:
@@ -56,6 +63,26 @@ class BSRMatrix:
     @property
     def K(self) -> int:
         return self.blocks.shape[1]
+
+    @property
+    def group(self) -> int:
+        """Kg, the blocks the kernel streams a grid step."""
+        return group_size(self.K, self.bm, self.bn)
+
+    @property
+    def streamed_share(self) -> float:
+        """Share of the (nbr x K) slot grid the kernel streams: the
+        groups up to each row's last stored block."""
+        return float(self.row_groups.sum()) * self.group / (self.nbr * self.K)
+
+    def streamed_bytes(self, nv: int = 1) -> int:
+        """HBM bytes one kernel apply reads and writes: the streamed
+        block groups, the iterate read into VMEM once per chunk call, and
+        the output."""
+        blocks = int(self.row_groups.sum()) * self.group * self.bm * self.bn
+        calls = len(row_chunks(self.nbr, self.K))
+        return 4 * (blocks + nv * (calls * self.nbc * self.bn
+                                   + self.nbr * self.bm))
 
     def device(self) -> Tuple[jax.Array, jax.Array]:
         return jnp.asarray(self.blocks), jnp.asarray(self.blk_cols)
@@ -111,7 +138,9 @@ def build_bsr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     """Pack COO triplets into the fixed-budget BSR layout.
 
     If a block-row holds more distinct nonzero block-columns than k_budget,
-    the budget is raised to the max (the kernel needs a static K).
+    the budget is raised to the max (the kernel needs a static K), then
+    padded with zero blocks to a multiple of the kernel's group size
+    (`group_size`).
     Set unique_pairs=True when no (row, col) repeats (graph edge lists) —
     the scatter then skips duplicate accumulation entirely.
     """
@@ -130,6 +159,9 @@ def build_bsr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     K = int(per_row.max()) if k_budget is None else max(k_budget,
                                                         int(per_row.max()))
     K = max(K, 1)
+    group = group_size(K, bm, bn)
+    K = -(-K // group) * group
+    row_groups = -(-per_row // group)
 
     # slot of each unique block within its block-row
     order = np.argsort(ub_row, kind="stable")
@@ -156,7 +188,8 @@ def build_bsr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
     # COO side); an all-zero-block BSR with fill 0 is the right answer
     fill = len(rows) / float(len(uniq) * bm * bn) if len(uniq) else 0.0
     return BSRMatrix(n_rows=n_rows, n_cols=n_cols, bm=bm, bn=bn,
-                     blocks=blocks, blk_cols=blk_cols, fill_ratio=fill)
+                     blocks=blocks, blk_cols=blk_cols, fill_ratio=fill,
+                     row_groups=row_groups.astype(np.int32))
 
 
 # --------------------------------------------------------------------------
@@ -168,7 +201,7 @@ class HybridBSR:
 
     The COO side is evaluated as gather + segment-sum over the *padded* row
     space, so a fused Google-apply can stay entirely in the kernel's
-    (n_blocks, block, nv) layout.
+    lane-dense (nv, n_blocks, block) layout.
     """
     bsr: BSRMatrix
     hub_rows: np.ndarray      # int32 (hub_nnz,) destination row of each edge
@@ -187,6 +220,7 @@ class HybridBSR:
     def device(self) -> dict:
         blocks, blk_cols = self.bsr.device()
         return dict(blocks=blocks, blk_cols=blk_cols,
+                    row_groups=jnp.asarray(self.bsr.row_groups),
                     hub_rows=jnp.asarray(self.hub_rows),
                     hub_cols=jnp.asarray(self.hub_cols),
                     hub_vals=jnp.asarray(self.hub_vals))
@@ -245,20 +279,20 @@ def hybrid_from_transition(pt: TransitionT, bm: int = DEFAULT_BM,
 
 
 def pad_x(x: np.ndarray, n_cols: int, bn: int) -> np.ndarray:
-    """(n, nv) or (n,) -> (nbc, bn, nv) padded block layout."""
+    """(n, nv) or (n,) -> (nv, nbc, bn) lane-dense padded block layout."""
     if x.ndim == 1:
         x = x[:, None]
     n, nv = x.shape
     nbc = -(-n_cols // bn)
-    xp = np.zeros((nbc * bn, nv), dtype=x.dtype)
-    xp[:n] = x
-    return xp.reshape(nbc, bn, nv)
+    xp = np.zeros((nv, nbc * bn), dtype=x.dtype)
+    xp[:, :n] = x.T
+    return xp.reshape(nv, nbc, bn)
 
 
 def unpad_y(y: np.ndarray, n_rows: int) -> np.ndarray:
-    """(nbr, bm, nv) -> (n_rows, nv)."""
-    nbr, bm, nv = y.shape
-    return y.reshape(nbr * bm, nv)[:n_rows]
+    """(nv, nbr, bm) -> (n_rows, nv)."""
+    nv, nbr, bm = y.shape
+    return y.reshape(nv, nbr * bm)[:, :n_rows].T
 
 
 IMPLS = ("auto", "pallas", "interpret", "ref")
@@ -281,7 +315,8 @@ def resolve_impl(impl: str = "auto") -> str:
 
 
 def bsr_matvec(blocks: jax.Array, blk_cols: jax.Array, x: jax.Array,
-               impl: str = "auto", accum: str = "f32") -> jax.Array:
+               impl: str = "auto", accum: str = "f32",
+               row_groups: Optional[jax.Array] = None) -> jax.Array:
     """Dispatch the block multiply: Pallas kernel, interpret mode, or the
     jnp blocked-einsum oracle (same math, XLA-compiled — the CPU path).
     impl="auto" resolves via `resolve_impl` (pallas on TPU, interpret
@@ -290,13 +325,12 @@ def bsr_matvec(blocks: jax.Array, blk_cols: jax.Array, x: jax.Array,
     scratch-carried Kahan sum, on the ref path the f64-accumulate limit
     cast back to f32), or "f64" (ref path only: full f64 accumulate,
     result in x's dtype; the kernel paths render it as "kahan" — the MXU
-    has no f64)."""
+    has no f64).  `row_groups` is the kernel's ragged stop (the oracle
+    reads every slot; padded ones are zero)."""
     impl = resolve_impl(impl)
-    if impl == "pallas":
-        return bsr_spmv(blocks, blk_cols, x, interpret=False,
-                        accum="f32" if accum == "f32" else "kahan")
-    if impl == "interpret":
-        return bsr_spmv(blocks, blk_cols, x, interpret=True,
+    if impl in ("pallas", "interpret"):
+        return bsr_spmv(blocks, blk_cols, x, row_groups,
+                        interpret=impl == "interpret",
                         accum="f32" if accum == "f32" else "kahan")
     return bsr_spmv_ref(blocks, blk_cols, x, accum=accum)
 
@@ -305,24 +339,24 @@ def hybrid_matvec(dev: dict, x: jax.Array, impl: str = "ref",
                   accum: str = "f32") -> jax.Array:
     """y = PT @ x in the padded block layout for a HybridBSR device dict.
 
-    x: (nbc, bn, nv) -> y: (nbr, bm, nv). The hub COO side is a gather +
+    x: (nv, nbc, bn) -> y: (nv, nbr, bm). The hub COO side is a gather +
     segment-sum over the padded row space, fused into the same jit scope
     (accumulated in the same lane as the block side: f64 when accum
     requests it and x64 is live).
     """
     y = bsr_matvec(dev["blocks"], dev["blk_cols"], x, impl=impl,
-                   accum=accum)
-    nbr, bm, nv = y.shape
-    xf = x.reshape(-1, nv)
+                   accum=accum, row_groups=dev["row_groups"])
+    nv, nbr, bm = y.shape
+    xf = x.reshape(nv, -1)
     if accum == "f32":
-        contrib = dev["hub_vals"][:, None] * xf[dev["hub_cols"]]
+        contrib = dev["hub_vals"][None, :] * xf[:, dev["hub_cols"]]
     else:
         wide = jax.dtypes.canonicalize_dtype(jnp.float64)
-        contrib = (dev["hub_vals"].astype(wide)[:, None]
-                   * xf.astype(wide)[dev["hub_cols"]])
-    hub = jax.ops.segment_sum(contrib, dev["hub_rows"],
+        contrib = (dev["hub_vals"].astype(wide)[None, :]
+                   * xf.astype(wide)[:, dev["hub_cols"]])
+    hub = jax.ops.segment_sum(contrib.T, dev["hub_rows"],
                               num_segments=nbr * bm)
-    return y + hub.reshape(nbr, bm, nv).astype(y.dtype)
+    return y + hub.T.reshape(nv, nbr, bm).astype(y.dtype)
 
 
 def spmv(bsr: BSRMatrix, x: jax.Array, interpret: bool = False,
@@ -334,10 +368,7 @@ def spmv(bsr: BSRMatrix, x: jax.Array, interpret: bool = False,
     kernel tests' explicit override; pass `impl=` ("auto"/"pallas"/
     "interpret"/"ref") to go through the auto-detecting dispatch instead.
     """
-    blocks, blk_cols = bsr.device()
-    if impl is not None:
-        return bsr_matvec(blocks, blk_cols, x, impl=impl, accum=accum)
-    if use_ref:
-        return bsr_spmv_ref(blocks, blk_cols, x, accum=accum)
-    return bsr_spmv(blocks, blk_cols, x, interpret=interpret,
-                    accum="f32" if accum == "f32" else "kahan")
+    if impl is None:
+        impl = "ref" if use_ref else ("interpret" if interpret else "pallas")
+    return bsr_matvec(*bsr.device(), x, impl=impl, accum=accum,
+                      row_groups=jnp.asarray(bsr.row_groups))

@@ -68,8 +68,8 @@ from .driver import TerminationDriver
 from .faults import (FaultPlan, FaultState, FaultyContext,
                      InjectedWorkerKill)
 from .observe import (EV_NAMES, OBS_COUNTERS, ShardObserver,
-                      attribute_frontier, chrome_trace, render_prometheus,
-                      span, write_chrome_trace)
+                      attribute_frontier, chrome_trace, kernel_counters,
+                      render_prometheus, span, write_chrome_trace)
 from .schedule import (DEFAULT_SCHEDULE, SCHEDULES, DrainOrder,
                        ExchangeGate, PriorityOrder, RandomizedOrder,
                        ScheduleSpec, make_schedule)
@@ -94,6 +94,7 @@ __all__ = [
     "BackoffPolicy", "RestartEvent", "ShardSupervisor",
     "ShardObserver", "EV_NAMES", "OBS_COUNTERS", "attribute_frontier",
     "chrome_trace", "write_chrome_trace", "render_prometheus", "span",
+    "kernel_counters",
     "ScheduleSpec", "SCHEDULES", "DEFAULT_SCHEDULE", "make_schedule",
     "DrainOrder", "PriorityOrder", "RandomizedOrder", "ExchangeGate",
     "Channel", "TransportContext", "WorkerConfig", "shard_worker_loop",

@@ -229,8 +229,8 @@ class DeviceShardTransport:
             if use_bsr:
                 op_args = tuple(
                     jax.device_put(packed[k], sh("ue", *([None] * nd)))
-                    for k, nd in (("blk", 4), ("bcols", 2), ("hrow", 1),
-                                  ("hcol", 1), ("hval", 1)))
+                    for k, nd in (("blk", 4), ("bcols", 2), ("rgroups", 1),
+                                  ("hrow", 1), ("hcol", 1), ("hval", 1)))
             else:
                 op_args = tuple(jax.device_put(packed[k], sh("ue", None))
                                 for k in ("src", "wgt", "rid"))

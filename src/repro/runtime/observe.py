@@ -117,6 +117,17 @@ def span(name: str, into: Optional[Dict[str, float]] = None
 
 
 # ---------------------------------------------------------------------------
+# kernel engagement (the BSR kernel's streaming, counted at pack time)
+# ---------------------------------------------------------------------------
+def kernel_counters(bsr, nv: int = 1) -> Dict[str, float]:
+    """How far the BSR kernel's ragged grid engages, from the packed
+    layout (a `kernels.bsr_spmv.BSRMatrix`): the share of its (nbr x K)
+    slot grid it streams, and the HBM bytes one apply moves at nv lanes."""
+    return {"bsr_streamed_block_share": bsr.streamed_share,
+            "bsr_streamed_bytes_per_apply": float(bsr.streamed_bytes(nv))}
+
+
+# ---------------------------------------------------------------------------
 # metrics registry schema (per-shard counter slots, single writer per row)
 # ---------------------------------------------------------------------------
 OBS_COUNTERS = (

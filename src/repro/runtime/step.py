@@ -379,8 +379,9 @@ def shard_pt_apply(op_slice: tuple, *, use_bsr: bool, bsize: int,
                    impl: str = "ref", accum: str = "f32"):
     """One shard's P^T apply over its operator slice.
 
-    op_slice: (blk, bcols, hrow, hcol, hval) for the BSR backend — the
-    Pallas block kernel plus the hub segment-sum side path — or
+    op_slice: (blk, bcols, rgroups, hrow, hcol, hval) for the BSR
+    backend — the Pallas block kernel (fed the view in its lane-dense
+    (nv, nbc, bn) layout) plus the hub segment-sum side path — or
     (src, wgt, rid) for the segment-sum backend.  `accum` threads the
     kernel's accumulation lane through (f32 | kahan | f64): with "f32" the
     view is cast to float32 on entry (the historic MXU contract); the
@@ -392,16 +393,17 @@ def shard_pt_apply(op_slice: tuple, *, use_bsr: bool, bsize: int,
 
     if use_bsr:
         from ..kernels.bsr_spmv import bsr_matvec
-        blk_, bcols_, hrow_, hcol_, hval_ = op_slice
+        blk_, bcols_, rgroups_, hrow_, hcol_, hval_ = op_slice
 
         def pt_apply(view):
             cast = view.astype(jnp.float32) if accum == "f32" else view
-            xb = cast.reshape(n_pad // bm, bm, nv)
-            y = bsr_matvec(blk_, bcols_, xb, impl=impl, accum=accum)
+            xb = cast.T.reshape(nv, n_pad // bm, bm)
+            y = bsr_matvec(blk_, bcols_, xb, impl=impl, accum=accum,
+                           row_groups=rgroups_)
             hub = jax.ops.segment_sum(
                 hval_.astype(cast.dtype)[:, None] * cast[hcol_],
                 hrow_, num_segments=bsize)
-            return (y.reshape(bsize, nv) + hub).astype(view.dtype)
+            return (y.reshape(nv, bsize).T + hub).astype(view.dtype)
         return pt_apply
 
     src_, wgt_, rid_ = op_slice

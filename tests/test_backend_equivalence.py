@@ -143,3 +143,42 @@ def test_rank_agreement_50k():
     bsr = solve_power(op, tol=1e-6, max_iters=300, backend="bsr_pallas")
     tau = kendall_tau_topk(ref.x, bsr.x, k=100)
     assert tau >= 0.999, tau
+
+
+def test_bsr_layout_round_trip():
+    """The bsr_pallas layout contract: `prepare` puts (n, nv) stacks in the
+    lane-dense (nv, nbr, bm) layout, padding exactly zero and the masks
+    lane-invariant (1, nbr, bm); `from_layout`/`unpad_y` take the stack
+    back, `take_lanes` selects lanes of the iterate and the teleport
+    stack, and `l1_residual` reduces every axis but the lanes."""
+    from repro.core.backend import (from_layout, l1_residual, prepare,
+                                    take_lanes)
+    op = _op(1000, 8000, 3)
+    spec = BackendSpec(name="bsr_pallas", impl="ref", bm=16).resolved()
+    rng = np.random.default_rng(0)
+    x = rng.random((op.n, 5)).astype(np.float32)
+    v = rng.random((op.n, 5))
+    v /= v.sum(axis=0)
+    dev, meta, x_dev = prepare(op, spec, jnp.float32, v=v, x0=x)
+    nbr = -(-op.n // 16)
+    assert x_dev.shape == dev["v"].shape == (5, nbr, 16)
+    assert dev["valid"].shape == dev["dang"].shape == (1, nbr, 16)
+    assert float(dev["valid"].sum()) == op.n
+    flat = np.asarray(x_dev).reshape(5, -1)
+    np.testing.assert_array_equal(flat[:, :op.n], x.T)
+    assert not flat[:, op.n:].any()
+    np.testing.assert_array_equal(from_layout(meta, x_dev), x)
+    np.testing.assert_array_equal(unpad_y(np.asarray(x_dev), op.n), x)
+    np.testing.assert_array_equal(pad_x(x, op.n, 16), np.asarray(x_dev))
+
+    idx = np.array([3, 0, 3])
+    dev2, meta2, x2 = take_lanes(meta, dev, x_dev, idx)
+    assert meta2.nv == 3 and x2.shape == (3, nbr, 16)
+    np.testing.assert_array_equal(from_layout(meta2, x2), x[:, idx])
+    np.testing.assert_allclose(unpad_y(np.asarray(dev2["v"]), op.n),
+                               v[:, idx].astype(np.float32))
+    assert dev2["valid"] is dev["valid"]
+
+    y = x_dev * 2.0
+    np.testing.assert_allclose(np.asarray(l1_residual(meta, y, x_dev)),
+                               np.abs(x).sum(axis=0), rtol=1e-5)

@@ -16,14 +16,19 @@ import jax.numpy as jnp
 from repro.core.backend import BackendMeta, BackendSpec, google_apply
 from repro.core.pagerank import _solve_jit
 from repro.graph.generate import STANFORD_N, STANFORD_NNZ
-from repro.kernels.bsr_spmv import bsr_spmv
+from repro.kernels.bsr_spmv import bsr_spmv, group_size
 
 HBM_BYTES = 16 * 10**9          # one v5e chip
 BM = 128
 # hybrid_bsr(bm=128) of stanford_web_replica(seed=0): 2203 block-rows,
-# K=70 blocks per row, 6.9% of the links on the hub COO side
-NBR, K = 2203, 70
+# at most 70 stored blocks per row (K padded to a multiple of the group
+# size), 6.9% of the links on the hub COO side
+NBR = 2203
+K = -(-70 // group_size(70, BM, BM)) * group_size(70, BM, BM)
 HUB_NNZ = 160_000
+# the iterate in the old (nbr, bm, nv=1) layout: its lane axis padded to
+# 128 in HBM, so each pass over it read 144 MB for a 1.1 MB vector
+PADDED_ITERATE = f"f32[{NBR},{BM},1]{{2,1,0:T(8,128)}}"
 
 
 @pytest.fixture(scope="module")
@@ -54,36 +59,46 @@ def _device_bytes(compiled) -> int:
 
 @pytest.mark.parametrize("accum,nv", [("f32", 1), ("kahan", 1), ("f32", 16)])
 def test_bsr_spmv_stanford_shape(one_chip, accum, nv):
-    """The BSR kernel at the paper's graph size: a (nbr, K) column table
-    in SMEM would overflow its 1 MiB; the chunked flat table fits."""
-    fn = jax.jit(lambda b, c, x: bsr_spmv(b, c, x, accum=accum))
+    """The BSR kernel at the paper's graph size, in the lane-dense layout:
+    a (nbr, K) column table in SMEM would overflow its 1 MiB, the chunked
+    flat tables fit; the resident iterate, the output and the group's
+    pipeline buffers fit the kernel's VMEM budget (VPU form at nv=1, MXU
+    form at nv=16)."""
+    fn = jax.jit(lambda b, c, g, x: bsr_spmv(b, c, x, g, accum=accum))
     compiled = fn.lower(
         _shape(one_chip, (NBR, K, BM, BM), jnp.float32),
         _shape(one_chip, (NBR, K), jnp.int32),
-        _shape(one_chip, (NBR, BM, nv), jnp.float32)).compile()
+        _shape(one_chip, (NBR,), jnp.int32),
+        _shape(one_chip, (nv, NBR, BM), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_fused_power_loop_stanford_shape(one_chip):
     """solve_power(backend="bsr_pallas")'s fused while_loop, as the chip
-    runs it: the kernel inside, the ~10.1 GB block array not copied."""
+    runs it: the kernel inside, the ~10.1 GB block array not copied, and
+    the iterate compact: no array in the old lane-padded layout, and the
+    loop's temporaries under 0.2 GB (0.93 GB in that layout)."""
     meta = BackendMeta(spec=BackendSpec(name="bsr_pallas", impl="pallas",
                                         bm=BM),
                        n=STANFORD_N, nv=1, n_pad=NBR * BM, alpha=0.85)
     f32, i32 = jnp.float32, jnp.int32
     dev = dict(blocks=_shape(one_chip, (NBR, K, BM, BM), f32),
                blk_cols=_shape(one_chip, (NBR, K), i32),
+               row_groups=_shape(one_chip, (NBR,), i32),
                hub_rows=_shape(one_chip, (HUB_NNZ,), i32),
                hub_cols=_shape(one_chip, (HUB_NNZ,), i32),
                hub_vals=_shape(one_chip, (HUB_NNZ,), f32),
-               valid=_shape(one_chip, (NBR, BM, 1), f32),
-               dang=_shape(one_chip, (NBR, BM, 1), f32),
-               v=_shape(one_chip, (NBR, BM, 1), f32))
+               valid=_shape(one_chip, (1, NBR, BM), f32),
+               dang=_shape(one_chip, (1, NBR, BM), f32),
+               v=_shape(one_chip, (1, NBR, BM), f32))
     compiled = _solve_jit.lower(
-        dev, _shape(one_chip, (NBR, BM, 1), f32), _shape(one_chip, (1,), f32),
+        dev, _shape(one_chip, (1, NBR, BM), f32), _shape(one_chip, (1,), f32),
         meta=meta, linear=False, max_iters=1000).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert PADDED_ITERATE not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
     assert _device_bytes(compiled) < HBM_BYTES
 
 
