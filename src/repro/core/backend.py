@@ -41,6 +41,7 @@ from typing import Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+import scipy.sparse as sp
 
 from ..graph.google import GoogleOperator
 from ..graph.csr import pt_matvec
@@ -101,8 +102,13 @@ class BackendMeta:
     alpha: float
 
 
-def _as_stack(a: np.ndarray, n: int, what: str) -> np.ndarray:
-    a = np.asarray(a, dtype=np.float64)
+def _as_stack(a, n: int, what: str, sparse: bool = False):
+    """(n,) or (n, nv) -> (n, nv) float64; a scipy sparse stack is kept
+    sparse where `sparse`, else made dense."""
+    if sp.issparse(a):
+        a = a.tocsc() if sparse else a.toarray()
+    else:
+        a = np.asarray(a, dtype=np.float64)
     if a.ndim == 1:
         a = a[:, None]
     if a.shape[0] != n:
@@ -110,29 +116,34 @@ def _as_stack(a: np.ndarray, n: int, what: str) -> np.ndarray:
     return a
 
 
-def seed_stack(n: int, seed_sets, weight_sets=None) -> np.ndarray:
+def seed_stack(n: int, seed_sets, weight_sets=None) -> sp.csc_array:
     """Build an (n, nv) personalized-teleport stack from nv seed sets.
 
     Each column is a probability vector concentrated on that query's seeds
     (uniform over the set unless `weight_sets[i]` gives explicit weights,
     which are L1-normalized).  This is the lane layout `prepare` consumes:
     one fused solve over the stack amortizes every edge/block load across
-    all nv personalized problems.
+    all nv personalized problems.  The stack is sparse (scipy CSC, a few
+    entries a lane): `prepare` and the host residual touch only its seeds.
     """
     seed_sets = list(seed_sets)
     nv = len(seed_sets)
     if nv == 0:
         raise ValueError("seed_stack needs at least one seed set")
-    v = np.zeros((n, nv), dtype=np.float64)
+    rows, vals = [], []
     for i, seeds in enumerate(seed_sets):
         seeds = np.asarray(seeds, dtype=np.int64).ravel()
         w = None if weight_sets is None else weight_sets[i]
         if w is None:
-            v[seeds, i] = 1.0 / seeds.size
+            w = np.full(seeds.size, 1.0 / seeds.size)
         else:
             w = np.asarray(w, dtype=np.float64).ravel()
-            v[seeds, i] = w / w.sum()
-    return v
+            w = w / w.sum()
+        rows.append(seeds)
+        vals.append(w)
+    indptr = np.concatenate([[0], np.cumsum([r.size for r in rows])])
+    return sp.csc_array((np.concatenate(vals), np.concatenate(rows),
+                         indptr), shape=(n, nv))
 
 
 def as_lane_tol(tol, nv: int) -> np.ndarray:
@@ -163,17 +174,16 @@ def prepare(op: GoogleOperator, spec: BackendSpec, dtype,
     operator; only the teleport stack is uploaded per call.
     """
     n = op.n
-    v_stack = _as_stack(op.teleport() if v is None else v, n, "teleport v")
+    v_stack = _as_stack(op.teleport() if v is None else v, n, "teleport v",
+                        sparse=spec.name == "bsr_pallas")
     nv = v_stack.shape[1]
-    if x0 is None:
-        x0_stack = np.full((n, nv), 1.0 / n, dtype=np.float64)
-    else:
-        x0_stack = _as_stack(x0, n, "x0")
-    if x0_stack.shape[1] != nv:
+    x0_stack = None if x0 is None else _as_stack(x0, n, "x0")
+    if x0_stack is not None and x0_stack.shape[1] != nv:
         if x0_stack.shape[1] == 1:
             x0_stack = np.broadcast_to(x0_stack, (n, nv)).copy()
         elif nv == 1:
-            v_stack = np.broadcast_to(v_stack, (n, x0_stack.shape[1])).copy()
+            v_stack = np.broadcast_to(_as_stack(v_stack, n, "teleport v"),
+                                      (n, x0_stack.shape[1])).copy()
             nv = v_stack.shape[1]
         else:
             raise ValueError(
@@ -184,7 +194,8 @@ def prepare(op: GoogleOperator, spec: BackendSpec, dtype,
         dev["v"] = jnp.asarray(v_stack, dtype=dtype)
         meta = BackendMeta(spec=spec, n=n, nv=nv, n_pad=n,
                            alpha=float(op.alpha))
-        x0_dev = jnp.asarray(x0_stack, dtype=dtype)
+        x0_dev = (jnp.full((n, nv), 1.0 / n, dtype=dtype) if x0 is None
+                  else jnp.asarray(x0_stack, dtype=dtype))
         return dev, meta, x0_dev
 
     # ---- bsr_pallas ----------------------------------------------------
@@ -202,19 +213,25 @@ def prepare(op: GoogleOperator, spec: BackendSpec, dtype,
         cache[key] = dev_struct
     dev = dict(dev_struct)
     nbr = hyb.bsr.nbr
-    dev["v"] = jnp.asarray(pad_x(v_stack.astype(np.float32), n, bm))
+    dev["v"] = jnp.asarray(pad_x(v_stack, n, bm, dtype=np.float32))
     meta = BackendMeta(spec=spec, n=n, nv=nv, n_pad=nbr * bm,
                        alpha=float(op.alpha))
-    x0_dev = jnp.asarray(pad_x(x0_stack.astype(np.float32), n, bm))
+    if x0 is None:
+        # the uniform start, made on the device: nothing to upload
+        x0_dev = jnp.broadcast_to(dev["valid"] * np.float32(1.0 / n),
+                                  (nv, nbr, bm))
+    else:
+        x0_dev = jnp.asarray(pad_x(x0_stack, n, bm, dtype=np.float32))
     return dev, meta, x0_dev
 
 
 def from_layout(meta: BackendMeta, x_dev) -> np.ndarray:
-    """Backend layout -> (n, nv) float64 numpy."""
-    x = np.asarray(x_dev, dtype=np.float64)
+    """Backend layout -> (n, nv) float64 numpy, a C-ordered array of its
+    own (one copy from the device's, transposed on the way)."""
+    x = np.asarray(x_dev)
     if meta.spec.name == "segment_sum":
-        return x
-    return unpad_y(x, meta.n)
+        return np.array(x, dtype=np.float64)
+    return unpad_y(x, meta.n).astype(np.float64, order="C")
 
 
 # --------------------------------------------------------------------------
@@ -269,10 +286,16 @@ def take_lanes(meta: BackendMeta, dev: dict, x: jax.Array,
     of the fused apply so the remaining lanes stop paying for them.  Only
     the teleport stack and the iterate carry a lane axis; the structural
     device state (edges, blocks, masks) is lane-invariant and shared.
+    The lanes go through the host: a gather on the device would compile a
+    program for every (from, to) pair of widths, a copy compiles nothing.
     """
     idx = np.asarray(idx, dtype=np.int64)
     ax = lane_axis(meta)
+
+    def take(a):
+        return jnp.asarray(np.take(np.asarray(a), idx, axis=ax))
+
     dev = dict(dev)
-    dev["v"] = jnp.take(dev["v"], idx, axis=ax)
+    dev["v"] = take(dev["v"])
     meta = dataclasses.replace(meta, nv=int(idx.size))
-    return dev, meta, jnp.take(x, idx, axis=ax)
+    return dev, meta, take(x)

@@ -20,6 +20,7 @@ from typing import Optional, Union
 import numpy as np
 import jax
 import jax.numpy as jnp
+import scipy.sparse as sp
 
 from ..graph.google import GoogleOperator
 from ..runtime.observe import kernel_counters, span
@@ -38,16 +39,21 @@ class SolveResult:
                                                 # (differs under freezing)
     kernel: Optional[dict] = None  # bsr_pallas: runtime.observe's
                                    # kernel_counters of the packed layout
+    widths: Optional[list] = None  # [(lanes, iterations)] of each chunk
+                                   # the fused loop ran between host
+                                   # rechecks (one chunk without freezing)
 
 
-@partial(jax.jit, static_argnames=("meta", "linear", "max_iters"))
+@partial(jax.jit, static_argnames=("meta", "linear"))
 def _solve_jit(dev: dict, x0: jax.Array, tol: jax.Array, *,
-               meta: BackendMeta, linear: bool, max_iters: int):
+               meta: BackendMeta, linear: bool, max_iters):
     """Fused fixed-point loop: the iterate never leaves the backend layout
     (for bsr_pallas that is the padded (nv, nbr, bm) block layout — no
     repacking between iterations).  `tol` is a traced (nv,) per-lane
     residual threshold (mixed-tol query batches share one compiled loop;
-    a scalar tol also no longer triggers a recompile per value)."""
+    a scalar tol also no longer triggers a recompile per value), and
+    `max_iters` a traced int32 scalar, so every chunk length of the
+    freezing driver runs one program per lane width."""
     def cond(state):
         _, resid, it = state
         return jnp.logical_and(jnp.any(resid > tol), it < max_iters)
@@ -64,13 +70,21 @@ def _solve_jit(dev: dict, x0: jax.Array, tol: jax.Array, *,
     return x, resid, iters
 
 
+def _run_loop(dev, x0, tol, meta: BackendMeta, linear: bool,
+              max_iters: int):
+    """`_solve_jit` with the operands in the one form every call shares
+    (float tol in the iterate's dtype, int32 max_iters), so no call
+    compiles a program of its own for an argument's type."""
+    tol = jnp.asarray(np.asarray(tol, dtype=x0.dtype))
+    return _solve_jit(dev, x0, tol, meta=meta, linear=linear,
+                      max_iters=np.int32(max_iters))
+
+
 def _pow2(k: int) -> int:
     return 1 << max(k - 1, 0).bit_length()
 
 
-# recheck cadences the adaptive driver may pick — `max_iters` is a static
-# jit arg, so arbitrary chunk lengths would each compile a fresh fused
-# loop; a pow2 menu bounds that axis to 6 entries shared across solves
+# recheck cadences the adaptive driver may pick: a pow2 menu of 6 entries
 _CHUNK_MENU = (8, 16, 32, 64, 128, 256)
 
 
@@ -114,13 +128,14 @@ def _solve_frozen(dev, x_dev, meta: BackendMeta, linear: bool,
     default ``"auto"`` adapts it to the observed per-lane iteration
     spread (see `_adapt_chunk`) — the first chunk is a fixed probe, every
     later one is scheduled at the fastest survivor's predicted tol
-    crossing.
+    crossing.  Each recheck (and compaction) is a `solver.recheck` span;
+    the (width, iterations) of every chunk is returned last.
     """
     nv = meta.nv
     n = meta.n
     adaptive = chunk == "auto"
     cur = 32 if adaptive else max(int(chunk), 1)
-    x_out = np.empty((n, nv))
+    x_out = None                    # (n, nv) once a lane has frozen
     resid_out = np.full(nv, np.inf)
     lane_iters = np.zeros(nv, dtype=np.int64)
     active = np.arange(nv)          # lane ids at stack positions 0..k-1
@@ -132,47 +147,55 @@ def _solve_frozen(dev, x_dev, meta: BackendMeta, linear: bool,
         dev, meta, x_dev = take_lanes(meta, dev, x_dev, pad)
         stack_tol = stack_tol[pad]
     it_total = 0
+    widths = []
     prev_resid = None               # survivors' residuals a chunk ago
     while True:
         step = min(cur, max_iters - it_total)
-        x_dev, resid_dev, it = _solve_jit(
-            dev, x_dev, jnp.asarray(stack_tol, x_dev.dtype), meta=meta,
-            linear=linear, max_iters=step)
-        it = int(it)
-        it_total += it
-        lane_iters[active] += it
-        resid_np = np.asarray(resid_dev, dtype=np.float64)[:active.size]
-        done = resid_np <= tol[active]
-        if done.all() or it_total >= max_iters:
-            x_np = from_layout(meta, x_dev)
-            x_out[:, active] = x_np[:, :active.size]
-            resid_out[active] = resid_np
-            break
-        if adaptive and it > 0:
-            if prev_resid is not None:
-                cur = _adapt_chunk(prev_resid[~done], resid_np[~done],
-                                   it, tol[active][~done], cur)
-            prev_resid = resid_np
-        new_width = _pow2(int((~done).sum()))
-        if done.any() and new_width < width:
-            # freeze + compact: record the converged lanes, keep the rest
-            frozen = active[done]
-            x_np = from_layout(meta, x_dev)
-            x_out[:, frozen] = x_np[:, :active.size][:, done]
-            resid_out[frozen] = resid_np[done]
-            keep_pos = np.flatnonzero(~done)
-            active = active[~done]
-            if prev_resid is not None:
-                prev_resid = prev_resid[~done]
-            idx = np.concatenate([keep_pos,
-                                  np.full(new_width - keep_pos.size,
-                                          keep_pos[0], np.int64)])
-            dev, meta, x_dev = take_lanes(meta, dev, x_dev, idx)
-            stack_tol = stack_tol[idx]
-            width = new_width
-        # lanes at <= tol that do not trigger a compaction stay in the
-        # stack (their slots exist anyway) and keep improving for free
-    return x_out, resid_out, it_total, lane_iters
+        x_dev, resid_dev, it = _run_loop(dev, x_dev, stack_tol, meta,
+                                         linear, step)
+        with span("solver.recheck"):
+            it = int(it)
+            it_total += it
+            widths.append((width, it))
+            lane_iters[active] += it
+            resid_np = np.asarray(resid_dev, dtype=np.float64)[:active.size]
+            done = resid_np <= tol[active]
+            if done.all() or it_total >= max_iters:
+                x_np = from_layout(meta, x_dev)
+                if x_out is None:       # no lane froze: the stack in order
+                    x_out = np.ascontiguousarray(x_np[:, :nv])
+                else:
+                    x_out[:, active] = x_np[:, :active.size]
+                resid_out[active] = resid_np
+                break
+            if adaptive and it > 0:
+                if prev_resid is not None:
+                    cur = _adapt_chunk(prev_resid[~done], resid_np[~done],
+                                       it, tol[active][~done], cur)
+                prev_resid = resid_np
+            new_width = _pow2(int((~done).sum()))
+            if done.any() and new_width < width:
+                # freeze + compact: record the converged lanes, keep the
+                # rest
+                frozen = active[done]
+                x_np = from_layout(meta, x_dev)
+                if x_out is None:
+                    x_out = np.empty((n, nv))
+                x_out[:, frozen] = x_np[:, :active.size][:, done]
+                resid_out[frozen] = resid_np[done]
+                keep_pos = np.flatnonzero(~done)
+                active = active[~done]
+                if prev_resid is not None:
+                    prev_resid = prev_resid[~done]
+                idx = np.concatenate([keep_pos,
+                                      np.full(new_width - keep_pos.size,
+                                              keep_pos[0], np.int64)])
+                dev, meta, x_dev = take_lanes(meta, dev, x_dev, idx)
+                stack_tol = stack_tol[idx]
+                width = new_width
+            # lanes at <= tol that do not trigger a compaction stay in the
+            # stack (their slots exist anyway) and keep improving for free
+    return x_out, resid_out, it_total, lane_iters, widths
 
 
 def solve_power(op: GoogleOperator, x0: Optional[np.ndarray] = None,
@@ -248,7 +271,8 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
     if reorder is not None:
         op, perm = _reordered(op, reorder)
         if v is not None:
-            v = np.asarray(v, dtype=np.float64)
+            v = v.toarray() if sp.issparse(v) else np.asarray(
+                v, dtype=np.float64)
             vp = np.empty_like(v)
             vp[perm] = v
             v = vp
@@ -271,23 +295,23 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
                   else bool(freeze_lanes)) and meta.nv > 1
         with span("solver.loop"):
             if freeze:
-                x, resid, iters, lane_iters = _solve_frozen(
+                x, resid, iters, lane_iters, widths = _solve_frozen(
                     dev, x0_dev, meta, linear, tol_vec, max_iters,
                     freeze_chunk)
             else:
-                x_dev, resid, iters = _solve_jit(
-                    dev, x0_dev, jnp.asarray(tol_vec, x0_dev.dtype),
-                    meta=meta, linear=linear, max_iters=max_iters)
+                x_dev, resid, iters = _run_loop(dev, x0_dev, tol_vec, meta,
+                                                linear, max_iters)
                 x = from_layout(meta, x_dev)
                 resid = np.asarray(resid, dtype=np.float64)
                 iters = int(iters)
                 lane_iters = np.full(meta.nv, iters, dtype=np.int64)
+                widths = [(meta.nv, iters)]
 
     with span("solver.finish"):
         if perm is not None:
             x = x[perm]
         s = x.sum(axis=0)
-        x = np.where(s > 0, x / np.where(s > 0, s, 1.0), x)
+        x /= np.where(s > 0, s, 1.0)        # x is the solve's own array
     nv = x.shape[1]
     if squeeze and nv == 1:
         x = x[:, 0]
@@ -297,7 +321,7 @@ def _solve(op, x0, tol, max_iters, linear, dtype, backend="segment_sum",
             bm=spec.bm, bn=spec.bm, hub_quantile=spec.hub_quantile).bsr, nv)
     return SolveResult(x=x, iters=int(iters), resid_l1=float(resid.max()),
                        resid_per_vec=resid if nv > 1 else None,
-                       lane_iters=lane_iters, kernel=kernel)
+                       lane_iters=lane_iters, kernel=kernel, widths=widths)
 
 
 def rank_of(x: np.ndarray) -> np.ndarray:

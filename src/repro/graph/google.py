@@ -11,6 +11,7 @@ Both preserve ||x||_1 = 1 for the power form when x0 is a distribution.
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Optional
 
 import numpy as np
@@ -78,21 +79,67 @@ class GoogleOperator:
         return y
 
     def apply_linear_numpy(self, x: np.ndarray,
-                           pt_sp: Optional[sp.csr_matrix] = None) -> np.ndarray:
+                           pt_sp: Optional[sp.csr_matrix] = None,
+                           v=None, rows: Optional[tuple] = None
+                           ) -> np.ndarray:
         """y = R x + b with R = alpha S, b = (1 - alpha) v.
 
-        `x` may be an (n, nv) stack; with a lane-stacked teleport `v` this
-        is the host-side exact residual route for batched personalized
-        solves (one spmm certifies every lane)."""
+        `x` may be an (n, nv) stack; with a lane-stacked teleport `v` (the
+        operator's own unless given: dense, or a scipy sparse (n, nv)
+        stack of seeds) this is the host-side exact residual route for
+        batched personalized solves (one spmm certifies every lane).
+        `rows=(a, b)` computes rows a..b-1 of y alone."""
         pt_sp = self.to_scipy_pt() if pt_sp is None else pt_sp
-        v = self.teleport()
-        if x.ndim == 2 and v.ndim == 1:
-            v = v[:, None]
+        v = self.teleport() if v is None else v
+        a, b = (0, self.n) if rows is None else rows
+        if rows is not None:
+            pt_sp = pt_sp.tocsr()
+            ip = pt_sp.indptr
+            pt_sp = sp.csr_matrix(
+                (pt_sp.data[ip[a]:ip[b]], pt_sp.indices[ip[a]:ip[b]],
+                 ip[a:b + 1] - ip[a]), shape=(b - a, pt_sp.shape[1]))
         dangling_mass = x[self.pt.dangling].sum(axis=0)
         y = self.alpha * (pt_sp @ x)
         y += self.alpha * dangling_mass / self.n
-        y += (1.0 - self.alpha) * v
+        if sp.issparse(v):
+            c = v.tocoo()
+            at = (c.row >= a) & (c.row < b)
+            np.add.at(y, (c.row[at] - a, c.col[at]),
+                      (1.0 - self.alpha) * c.data[at])
+        else:
+            if x.ndim == 2 and v.ndim == 1:
+                v = v[:, None]
+            y += (1.0 - self.alpha) * v[a:b]
         return y
+
+    def residual_l1_numpy(self, x: np.ndarray,
+                          pt_sp: Optional[sp.csr_matrix] = None, v=None,
+                          workers: int = 8) -> np.ndarray:
+        """||R x + b - x||_1 of each lane of an (n, nv) stack, exactly in
+        float64: `apply_linear_numpy` over row blocks on `workers`
+        threads (scipy's spmm releases the GIL), each block summed on its
+        own, so no (n, nv) array is made."""
+        from concurrent.futures import ThreadPoolExecutor
+        pt_sp = self.to_scipy_pt() if pt_sp is None else pt_sp
+        x = np.ascontiguousarray(x, dtype=np.float64)
+        workers = max(1, min(workers, os.cpu_count() or 1))
+        cuts = np.linspace(0, self.n, 4 * workers + 1).astype(np.int64)
+
+        def block(a, b):
+            r = self.apply_linear_numpy(x, pt_sp, v, rows=(a, b))
+            r -= x[a:b]
+            return np.abs(r, out=r).sum(axis=0)
+
+        with ThreadPoolExecutor(workers) as pool:
+            return sum(pool.map(block, cuts[:-1], cuts[1:]))
+
+    def drop_layouts(self) -> None:
+        """Let go of the memoized BSR pack and its device arrays, the
+        largest state an operator holds (10.1 GB at Stanford-Web)."""
+        cache = self._cache()
+        for k in list(cache):
+            if k[0] in ("hybrid", "bsr_dev"):
+                del cache[k]
 
     # ---------------- JAX path ------------------------------------------
     def device_arrays(self, dtype=jnp.float32) -> dict:

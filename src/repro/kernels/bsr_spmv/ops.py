@@ -21,11 +21,19 @@ from typing import Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+import scipy.sparse as sp
 
 from .bsr_spmv import (bsr_spmv, group_size, row_chunks, DEFAULT_BM,
                        DEFAULT_BN)
 from .ref import bsr_spmv_ref
 from ...graph.csr import TransitionT
+
+# links a hub row sums in one accumulator before its partial sums are
+# added: an f32 scatter-add accumulates serially, and on a v5e one hub row
+# of 79,727 links summed in one accumulator came out 3.5e-4 low on a
+# personalized lane (its certificate missed tol 1e-4); two levels of at
+# most ~sqrt(links) terms each bound what any accumulator sees
+HUB_SLOT_LINKS = 256
 
 # the dense-block array is uploaded whole to one device, so refuse to
 # pack one that cannot be solved on a v5e chip: 16 GB of HBM less 1 GB
@@ -219,11 +227,29 @@ class HybridBSR:
 
     def device(self) -> dict:
         blocks, blk_cols = self.bsr.device()
+        slots, slot_rows = hub_slots(self.hub_rows)
         return dict(blocks=blocks, blk_cols=blk_cols,
                     row_groups=jnp.asarray(self.bsr.row_groups),
-                    hub_rows=jnp.asarray(self.hub_rows),
+                    hub_slots=jnp.asarray(slots),
+                    hub_slot_rows=jnp.asarray(slot_rows),
                     hub_cols=jnp.asarray(self.hub_cols),
                     hub_vals=jnp.asarray(self.hub_vals))
+
+
+def hub_slots(rows: np.ndarray, links: int = HUB_SLOT_LINKS
+              ) -> Tuple[np.ndarray, np.ndarray]:
+    """(slot of each hub link, row of each slot), int32: each row's links
+    fill consecutive slots of at most `links`, so the hub side sums every
+    row in two levels (links into slots, slots into rows)."""
+    rows = np.asarray(rows, dtype=np.int64)
+    order = np.argsort(rows, kind="stable")
+    r = rows[order]
+    pos = np.arange(r.size) - np.searchsorted(r, r)   # index within its row
+    per = int(pos.max()) // links + 1 if r.size else 1
+    key, slot_sorted = np.unique(r * per + pos // links, return_inverse=True)
+    slots = np.empty(r.size, dtype=np.int32)
+    slots[order] = slot_sorted
+    return slots, (key // per).astype(np.int32)
 
 
 def build_hybrid_bsr(rows: np.ndarray, cols: np.ndarray, vals: np.ndarray,
@@ -278,13 +304,20 @@ def hybrid_from_transition(pt: TransitionT, bm: int = DEFAULT_BM,
                             hub_quantile=hub_quantile, unique_pairs=True)
 
 
-def pad_x(x: np.ndarray, n_cols: int, bn: int) -> np.ndarray:
-    """(n, nv) or (n,) -> (nv, nbc, bn) lane-dense padded block layout."""
+def pad_x(x, n_cols: int, bn: int, dtype=None) -> np.ndarray:
+    """(n, nv) or (n,) -> (nv, nbc, bn) lane-dense padded block layout, in
+    `dtype` (x's own by default). `x` may be a scipy sparse (n, nv) stack
+    (a few seed pages a lane): only its stored entries are written."""
+    nbc = -(-n_cols // bn)
+    if sp.issparse(x):
+        c = x.tocoo()
+        xp = np.zeros((c.shape[1], nbc * bn), dtype=dtype or c.dtype)
+        np.add.at(xp, (c.col, c.row), c.data)
+        return xp.reshape(c.shape[1], nbc, bn)
     if x.ndim == 1:
         x = x[:, None]
     n, nv = x.shape
-    nbc = -(-n_cols // bn)
-    xp = np.zeros((nv, nbc * bn), dtype=x.dtype)
+    xp = np.zeros((nv, nbc * bn), dtype=dtype or x.dtype)
     xp[:, :n] = x.T
     return xp.reshape(nv, nbc, bn)
 
@@ -339,8 +372,9 @@ def hybrid_matvec(dev: dict, x: jax.Array, impl: str = "ref",
                   accum: str = "f32") -> jax.Array:
     """y = PT @ x in the padded block layout for a HybridBSR device dict.
 
-    x: (nv, nbc, bn) -> y: (nv, nbr, bm). The hub COO side is a gather +
-    segment-sum over the padded row space, fused into the same jit scope
+    x: (nv, nbc, bn) -> y: (nv, nbr, bm). The hub COO side is a gather and
+    two segment-sums (links into slots of at most `HUB_SLOT_LINKS`, slots
+    into the padded row space), fused into the same jit scope
     (accumulated in the same lane as the block side: f64 when accum
     requests it and x64 is live).
     """
@@ -354,8 +388,10 @@ def hybrid_matvec(dev: dict, x: jax.Array, impl: str = "ref",
         wide = jax.dtypes.canonicalize_dtype(jnp.float64)
         contrib = (dev["hub_vals"].astype(wide)[None, :]
                    * xf.astype(wide)[:, dev["hub_cols"]])
-    hub = jax.ops.segment_sum(contrib.T, dev["hub_rows"],
-                              num_segments=nbr * bm)
+    slot_rows = dev["hub_slot_rows"]
+    part = jax.ops.segment_sum(contrib.T, dev["hub_slots"],
+                               num_segments=slot_rows.shape[0])
+    hub = jax.ops.segment_sum(part, slot_rows, num_segments=nbr * bm)
     return y + hub.T.reshape(nv, nbr, bm).astype(y.dtype)
 
 

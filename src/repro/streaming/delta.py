@@ -406,13 +406,17 @@ class DeltaGraph:
 
     def _gc_views(self, keep: int = 2) -> None:
         """Drop memoized views older than the last `keep` versions (their
-        device/BSR caches go with them)."""
+        device/BSR caches go with them), and the BSR layouts of every
+        version but the current one: one layout is all a chip holds."""
         floor = self.version - keep
         for d in (self._snap, self._pt, self._pt_sp):
             for k in [k for k in d if k < floor]:
                 del d[k]
         for k in [k for k in self._ops if k[0] < floor]:
             del self._ops[k]
+        for k, op in self._ops.items():
+            if k[0] < self.version:
+                op.drop_layouts()
 
     # ------------------------------------------------------------------
     # materialized views (memoized per version)
@@ -493,8 +497,13 @@ class DeltaGraph:
 
         The uniform-teleport view is memoized per (version, alpha) — its
         device/BSR caches persist across every fallback solve at this
-        version.  Personalized views are built fresh but share this
-        version's `TransitionT`, so the edge device arrays still carry.
+        version, and its BSR layout is dropped once a newer version is
+        applied.  Personalized views are built fresh: they share this
+        version's `TransitionT`, and with it only the segment-sum edge
+        arrays it uploaded; the BSR layout is memoized per operator, so a
+        personalized view would pack and upload it again.  Batched
+        personalized solves therefore pass their teleport stack as `v=`
+        to a solve on the memoized view (`ppr_push_batched`).
         """
         if v is not None:
             return GoogleOperator(pt=self.transition(), alpha=alpha, v=v)

@@ -39,6 +39,7 @@ import numpy as np
 
 from ..core.backend import as_lane_tol, seed_stack
 from ..core.pagerank import solve_linear, solve_power
+from ..runtime.observe import span
 from ..runtime.schedule import make_schedule
 from .delta import DeltaGraph, EdgeDelta
 
@@ -651,6 +652,16 @@ class BatchedPPRStats:
     lane_iters: np.ndarray    # (nv,) per-lane iterations under freezing
     certs: np.ndarray         # (nv,) exact per-lane certificates
     tol: np.ndarray           # (nv,) per-lane requested tolerances
+    widths: list = dataclasses.field(default_factory=list)
+                              # [(lanes, iterations)] of each chunk run
+                              # between host rechecks; they sum to iters
+    kernel: Optional[dict] = None   # bsr_pallas: the solve's
+                                    # kernel_counters (SolveResult.kernel)
+
+    @property
+    def chunks(self) -> int:
+        """Host rechecks in the batch: one after each chunk."""
+        return len(self.widths)
 
 
 def _host_stack_solve(pt_sp, dangling_idx: np.ndarray, alpha: float,
@@ -674,6 +685,7 @@ def _host_stack_solve(pt_sp, dangling_idx: np.ndarray, alpha: float,
     lane_iters = np.zeros(nv, dtype=np.int64)
     active = np.arange(nv)
     it = 0
+    widths = []
     while active.size and it < max_iters:
         y = alpha * (pt_sp @ x)
         y += (alpha / n) * x[dangling_idx].sum(axis=0)[None, :]
@@ -682,6 +694,10 @@ def _host_stack_solve(pt_sp, dangling_idx: np.ndarray, alpha: float,
         x = y
         it += 1
         lane_iters[active] += 1
+        if widths and widths[-1][0] == active.size:
+            widths[-1] = (active.size, widths[-1][1] + 1)
+        else:
+            widths.append((active.size, 1))
         done = resid <= tol_res[active]
         if done.any():
             out[:, active[done]] = x[:, done]
@@ -689,7 +705,7 @@ def _host_stack_solve(pt_sp, dangling_idx: np.ndarray, alpha: float,
             active = active[~done]
     if active.size:                      # max_iters hit: flush as-is
         out[:, active] = x
-    return out, lane_iters, it
+    return out, lane_iters, it, widths
 
 
 def ppr_push_batched(view, seed_sets, weight_sets=None, *,
@@ -721,12 +737,19 @@ def ppr_push_batched(view, seed_sets, weight_sets=None, *,
     feeds the host path and the exact certification; it is derived from
     `op`/`view` when omitted.
 
+    The jax backends solve on `op` itself with the stack passed as `v=`,
+    so the layout memoized on the operator of the view's version (the
+    hybrid BSR: a host pack and an upload of the whole layout) is built
+    once per graph version, not once per batch.
+
     Returns (X, certs, stats): X is the (n, nv) column-per-query result,
     and each certs[i] = ||x_i - x*_i||_1 bound is recomputed *exactly*
     (one host spmm over all lanes) — never the solver's own residual — so
     the published certificates match `update_ranks`' contract.  A lane
     whose cert misses its tol (e.g. the bsr_pallas f32 floor) warns via
-    `_check_cert` and reports the true, larger bound.
+    `_check_cert` and reports the true, larger bound.  The spans
+    `ppr.stack` (seed validation, teleport stack) and `ppr.certify` (the
+    exact residual) mark the host's own work.
     """
     if method not in ("linear", "power"):
         raise ValueError(f"unknown method {method!r}")
@@ -738,15 +761,18 @@ def ppr_push_batched(view, seed_sets, weight_sets=None, *,
         raise ValueError("backend='scipy' implements the linear form "
                          "only; use a jax backend for method='power'")
     n = view.n if view is not None else op.n
-    seed_sets = list(seed_sets)
-    nv = len(seed_sets)
-    if weight_sets is not None and len(weight_sets) != nv:
-        raise ValueError(f"{len(weight_sets)} weight sets for {nv} "
-                         "seed sets")
-    pairs = [validate_seeds(n, s, None if weight_sets is None
-                            else weight_sets[i])
-             for i, s in enumerate(seed_sets)]
-    tol_vec = as_lane_tol(tol, nv)
+    with span("ppr.stack"):
+        seed_sets = list(seed_sets)
+        nv = len(seed_sets)
+        if weight_sets is not None and len(weight_sets) != nv:
+            raise ValueError(f"{len(weight_sets)} weight sets for {nv} "
+                             "seed sets")
+        pairs = [validate_seeds(n, s, None if weight_sets is None
+                                else weight_sets[i])
+                 for i, s in enumerate(seed_sets)]
+        tol_vec = as_lane_tol(tol, nv)
+        v_stack = seed_stack(n, [s for s, _ in pairs],
+                             [w for _, w in pairs])
 
     if op is None:
         if not isinstance(view, DeltaGraph):
@@ -760,33 +786,35 @@ def ppr_push_batched(view, seed_sets, weight_sets=None, *,
     if pt_sp is None:
         pt_sp = op.to_scipy_pt()
 
-    from ..graph.google import GoogleOperator
-    v_stack = seed_stack(n, [s for s, _ in pairs], [w for _, w in pairs])
-    op_b = GoogleOperator(pt=op.pt, alpha=alpha, v=v_stack)
+    if op.alpha != alpha:
+        raise ValueError(f"op has alpha {op.alpha}, the query asks {alpha}")
     # same 0.5x headroom convention as cold_state: the exact recompute
     # below must land under (1 - alpha) * tol after solver exit
     tol_res = 0.5 * (1.0 - alpha) * tol_vec
+    kernel = None
     if backend == "scipy":
-        x, lane_iters, iters = _host_stack_solve(
-            pt_sp, np.flatnonzero(op.pt.dangling), alpha, v_stack,
-            tol_res, max_iters)
+        x, lane_iters, iters, widths = _host_stack_solve(
+            pt_sp, np.flatnonzero(op.pt.dangling), alpha,
+            v_stack.toarray(), tol_res, max_iters)
         path = "batched_host"
     else:
         solver = solve_linear if method == "linear" else solve_power
-        res = solver(op_b, tol=tol_res, max_iters=max_iters,
-                     backend=backend, freeze_lanes=freeze_lanes,
+        res = solver(op, tol=tol_res, max_iters=max_iters,
+                     backend=backend, v=v_stack, freeze_lanes=freeze_lanes,
                      freeze_chunk=freeze_chunk)
         x = np.asarray(res.x, dtype=np.float64)
         if x.ndim == 1:
             x = x[:, None]
         lane_iters, iters = res.lane_iters, res.iters
+        widths, kernel = res.widths, res.kernel
         path = f"batched_{method}"
-    r = op_b.apply_linear_numpy(x, pt_sp=pt_sp) - x
-    resid = np.abs(r).sum(axis=0)
+    with span("ppr.certify"):
+        resid = op.residual_l1_numpy(x, pt_sp=pt_sp, v=v_stack)
     certs = resid / (1.0 - alpha)
     worst = int(np.argmax(certs / tol_vec))
     _check_cert(float(resid[worst]), float(tol_vec[worst]), alpha,
                 f"ppr_push_batched[{backend}] lane {worst}")
     return x, certs, BatchedPPRStats(
         path=path, nv=nv, iters=int(iters),
-        lane_iters=np.asarray(lane_iters), certs=certs, tol=tol_vec)
+        lane_iters=np.asarray(lane_iters), certs=certs, tol=tol_vec,
+        widths=widths, kernel=kernel)

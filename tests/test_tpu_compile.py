@@ -26,6 +26,9 @@ BM = 128
 NBR = 2203
 K = -(-70 // group_size(70, BM, BM)) * group_size(70, BM, BM)
 HUB_NNZ = 160_000
+# the hub side's two-level sum: ~2,820 hub rows, the largest split into
+# slots of at most HUB_SLOT_LINKS links
+HUB_SLOTS = 3_500
 # the iterate in the old (nbr, bm, nv=1) layout: its lane axis padded to
 # 128 in HBM, so each pass over it read 144 MB for a 1.1 MB vector
 PADDED_ITERATE = f"f32[{NBR},{BM},1]{{2,1,0:T(8,128)}}"
@@ -74,31 +77,40 @@ def test_bsr_spmv_stanford_shape(one_chip, accum, nv):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_fused_power_loop_stanford_shape(one_chip):
+# (lanes, linear form, bound on the loop's temporaries): at 16 lanes the
+# hub side's gather and scatter hold ~14 lane-major copies of the 18 MB
+# iterate (264 MB in a v5e compile), 2.6% of the chip
+@pytest.mark.parametrize("nv,linear,temps", [(1, False, 0.2e9),
+                                             (16, True, 0.3e9)])
+def test_fused_power_loop_stanford_shape(one_chip, nv, linear, temps):
     """solve_power(backend="bsr_pallas")'s fused while_loop, as the chip
-    runs it: the kernel inside, the ~10.1 GB block array not copied, and
+    runs it (and at nv=16, the linear form of a batch of personalized
+    queries, through the kernel's MXU form, with `max_iters` a traced
+    operand): the kernel inside, the ~10.1 GB block array not copied, and
     the iterate compact: no array in the old lane-padded layout, and the
-    loop's temporaries under 0.2 GB (0.93 GB in that layout)."""
+    loop's temporaries at nv=1 under 0.2 GB (0.93 GB in that layout)."""
     meta = BackendMeta(spec=BackendSpec(name="bsr_pallas", impl="pallas",
                                         bm=BM),
-                       n=STANFORD_N, nv=1, n_pad=NBR * BM, alpha=0.85)
+                       n=STANFORD_N, nv=nv, n_pad=NBR * BM, alpha=0.85)
     f32, i32 = jnp.float32, jnp.int32
     dev = dict(blocks=_shape(one_chip, (NBR, K, BM, BM), f32),
                blk_cols=_shape(one_chip, (NBR, K), i32),
                row_groups=_shape(one_chip, (NBR,), i32),
-               hub_rows=_shape(one_chip, (HUB_NNZ,), i32),
+               hub_slots=_shape(one_chip, (HUB_NNZ,), i32),
+               hub_slot_rows=_shape(one_chip, (HUB_SLOTS,), i32),
                hub_cols=_shape(one_chip, (HUB_NNZ,), i32),
                hub_vals=_shape(one_chip, (HUB_NNZ,), f32),
                valid=_shape(one_chip, (1, NBR, BM), f32),
                dang=_shape(one_chip, (1, NBR, BM), f32),
-               v=_shape(one_chip, (1, NBR, BM), f32))
+               v=_shape(one_chip, (nv, NBR, BM), f32))
     compiled = _solve_jit.lower(
-        dev, _shape(one_chip, (1, NBR, BM), f32), _shape(one_chip, (1,), f32),
-        meta=meta, linear=False, max_iters=1000).compile()
+        dev, _shape(one_chip, (nv, NBR, BM), f32),
+        _shape(one_chip, (nv,), f32), meta=meta, linear=linear,
+        max_iters=_shape(one_chip, (), i32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert PADDED_ITERATE not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    assert compiled.memory_analysis().temp_size_in_bytes < temps
     assert _device_bytes(compiled) < HBM_BYTES
 
 
